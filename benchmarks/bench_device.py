@@ -6,10 +6,10 @@ mode executes kernel bodies in Python and is not a timing proxy).
 refinement (legacy stable-argsort compaction over chained per-query MBR
 gathers, ``compaction="sort"``), the staged pipeline (slot-aligned MBR
 tables + cumsum/scatter compaction, ``compaction="scan"``) and the
-ONE-dispatch fused path (``batch_query_fused`` — its "reference" XLA
-composition on CPU, the Pallas kernel itself on TPU) per dataset and
-relation, asserts exactness against ``query_bruteforce`` every time, and
-emits the ``BENCH {json}`` line committed as ``BENCH_device.json``.
+whole-table fused path (``batch_query_fused``, one XLA program; on TPU the
+``pallas`` column adds the compact kernel) per dataset and relation,
+asserts exactness against ``query_bruteforce`` every time, and emits the
+``BENCH {json}`` line committed as ``BENCH_device.json``.
 ``knn_pipeline`` adds the ``"knn"`` row: the device-complete knn batch
 (CDF-seeded radii + device top-k) against the host-ranked rung ladder it
 replaced, both asserted exact against the fp64 brute-force oracle. The
@@ -56,8 +56,8 @@ def refine_pipeline(csv: Csv, n: int, q: int = 128) -> dict:
     """Old-vs-new refinement per dataset × relation at the tracked config
     (cap=4096, budget=256). ``refine_us`` isolates the refinement stage:
     total batched query time minus the (shared) probe time. ``fused`` is the
-    one-dispatch ``batch_query_fused`` path — the Pallas kernel on TPU, its
-    bit-identical "reference" XLA composition elsewhere (interpret mode is a
+    whole-table ``batch_query_fused`` XLA program; ``pallas`` (TPU only) is
+    ``batch_query`` with the compact kernel (interpret mode is a
     correctness tool, not a timing proxy)."""
     on_tpu = jax.default_backend() == "tpu"
     impls = ["sort", "scan", "fused"]
@@ -105,8 +105,7 @@ def refine_pipeline(csv: Csv, n: int, q: int = 128) -> dict:
                         # refinement delta fairly vs the staged columns
                         h, c = batch_query_fused(
                             snap, wj, pods, relation=base,
-                            exact_budget=REFINE_BUDGET,
-                            mode="pallas" if on_tpu else "reference")
+                            exact_budget=REFINE_BUDGET)
                         return h.block_until_ready(), c.block_until_ready()
                 else:
                     def run(impl=impl, wj=wj, cap=cap):

@@ -12,10 +12,15 @@ run, and emits the ``BENCH {json}`` line committed as ``BENCH_sharded.json``.
 Each mesh also times the device-complete knn tier (shard-local top-k + the
 one-collective k-merge) on the cluster dataset, exact vs the fp64 host loop.
 
-Device count is fixed per process, so the orchestrating ``run()`` spawns one
-``--inner`` subprocess per mesh size (the full matrix on the 4-way mesh, a
-cluster/intersects confirmation on the 2-way mesh) and merges their BENCH
-payloads.
+``run()`` measures the full matrix on a 4-way mesh and a cluster/intersects
+confirmation on a 2-way mesh, and merges their BENCH payloads:
+
+* on real devices (a TPU host) it runs every mesh in this process, over the
+  devices present — one process holds the chips, and a child could not
+  reach them;
+* for a CPU rehearsal (``JAX_PLATFORMS=cpu``) from a process that has not
+  imported JAX yet, it spawns one ``--inner`` child per mesh size with that
+  many fake CPU devices (the device count is fixed per process).
 
     PYTHONPATH=src python -m benchmarks.bench_sharded [--n 30000]
 """
@@ -27,6 +32,7 @@ import os
 import pathlib
 import subprocess
 import sys
+from typing import Optional
 
 import numpy as np
 
@@ -52,9 +58,10 @@ def _inner(csv: Csv, devices: int, n: int, q: int, full: bool) -> dict:
     from .common import timeit
 
     assert jax.device_count() >= devices, (
-        f"need {devices} devices, have {jax.device_count()} — the inner "
-        "bench must run with --xla_force_host_platform_device_count")
-    mesh = make_auto_mesh((devices, 1), ("data", "model"))
+        f"need {devices} devices, have {jax.device_count()} — a CPU run "
+        "needs --xla_force_host_platform_device_count")
+    mesh = make_auto_mesh((devices, 1), ("data", "model"),
+                          devices=jax.devices()[:devices])
 
     def engine(budget: int) -> EngineConfig:
         return EngineConfig(mesh=mesh, shard_min_records=1,
@@ -138,8 +145,10 @@ def _inner(csv: Csv, devices: int, n: int, q: int, full: bool) -> dict:
 
 def _spawn_inner(csv: Csv, devices: int, n: int, q: int, full: bool) -> dict:
     """Run ``--inner`` in a subprocess with ``devices`` fake CPU devices and
-    parse its CSV rows + BENCH payload off stdout."""
+    parse its CSV rows + BENCH payload off stdout. CPU rehearsal only: the
+    child is pinned to the CPU backend and never needs an accelerator."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     flag = f"--xla_force_host_platform_device_count={devices}"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
     env["PYTHONPATH"] = os.pathsep.join(
@@ -167,11 +176,33 @@ def _spawn_inner(csv: Csv, devices: int, n: int, q: int, full: bool) -> dict:
     return payload
 
 
-def run(csv: Csv, large: bool = False, n: int = 30_000, q: int = 64) -> dict:
+def run(csv: Csv, large: bool = False, n: int = 30_000,
+        q: int = 64) -> Optional[dict]:
+    """Fake-CPU-device children for a CPU rehearsal (``JAX_PLATFORMS=cpu``
+    and JAX not yet imported here), else in-process over the real devices;
+    ``None`` (skipped, with a printed reason) on fewer than two devices."""
     if large:
         n = max(n, 200_000)
-    meshes = {"4": _spawn_inner(csv, 4, n, q, full=True),
-              "2": _spawn_inner(csv, 2, n, q, full=False)}
+    if (os.environ.get("JAX_PLATFORMS") == "cpu"
+            and "jax" not in sys.modules):
+        meshes = {"4": _spawn_inner(csv, 4, n, q, full=True),
+                  "2": _spawn_inner(csv, 2, n, q, full=False)}
+    else:
+        import jax
+
+        from repro.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
+        if jax.device_count() < 2:
+            print(f"# sharded bench skipped: it needs >= 2 devices and this "
+                  f"process has {jax.device_count()} "
+                  f"{jax.default_backend()} device(s) (for a CPU "
+                  "rehearsal set JAX_PLATFORMS=cpu in a process that has "
+                  "not imported JAX)", flush=True)
+            return None
+        meshes = {str(d): _inner(csv, d, n, q, full=(d == 4))
+                  for d in (4, 2) if d <= jax.device_count()}
+    main_mesh = max(meshes, key=int)
     speedups = [row["speedup"]
                 for payload in meshes.values()
                 for rels in payload["datasets"].values()
@@ -182,7 +213,7 @@ def run(csv: Csv, large: bool = False, n: int = 30_000, q: int = 64) -> dict:
         "q": q,
         "meshes": meshes,
         "speedup_cluster":
-            meshes["4"]["datasets"]["cluster"]["intersects"]["speedup"],
+            meshes[main_mesh]["datasets"]["cluster"]["intersects"]["speedup"],
         "min_speedup": min(speedups),
     }
     csv.emit("sharded/min_fused_vs_dense_speedup", 0.0,
@@ -204,6 +235,9 @@ def main() -> None:
     args = ap.parse_args()
     print("name,us_per_call,derived")
     if args.inner:
+        from repro.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         payload = _inner(Csv(), args.devices, args.n, args.q, args.full)
         print("BENCH " + json.dumps(payload))
     else:

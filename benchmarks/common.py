@@ -4,18 +4,23 @@ Real TIGER/OSM corpora are unavailable offline; datasets are the synthetic
 stand-ins from core.datasets (documented in DESIGN.md §6). Default scale is
 CPU-friendly (--large raises it). Output format: ``name,us_per_call,derived``
 CSV rows, one per measured quantity, mirroring a paper table/figure each.
+
+Importing this module does not import JAX (the repro imports are deferred
+into the helpers), so an orchestrator can still start CPU-rehearsal children
+after importing it.
 """
 from __future__ import annotations
 
 import functools
 import time
-from typing import Callable, List
+from typing import TYPE_CHECKING, Callable, List
 
 import numpy as np
 
-from repro.core.datasets import GeometrySet, generate, make_query_windows
-from repro.core.engine import EngineConfig, SpatialIndex
-from repro.core.index import GLIN, GLINConfig
+if TYPE_CHECKING:
+    from repro.core.datasets import GeometrySet
+    from repro.core.engine import EngineConfig, SpatialIndex
+    from repro.core.index import GLIN
 
 SELECTIVITIES = [0.01, 0.001, 0.0001, 0.00001]  # 1% .. 0.001% of N
 DATASETS = ["cluster", "uniform", "roads", "concave", "mixed"]
@@ -23,17 +28,24 @@ DATASETS = ["cluster", "uniform", "roads", "concave", "mixed"]
 
 @functools.lru_cache(maxsize=16)
 def dataset(name: str, n: int, seed: int = 0) -> GeometrySet:
+    from repro.core.datasets import generate
+
     return generate(name, n, seed=seed)
 
 
 @functools.lru_cache(maxsize=32)
 def windows(name: str, n: int, sel: float, k: int = 20, seed: int = 0):
+    from repro.core.datasets import make_query_windows
+
     return make_query_windows(dataset(name, n), sel, k, seed=seed)
 
 
 def build_index(name: str, n: int, pl: int = 10000,
                 engine: "EngineConfig | None" = None, **kw) -> SpatialIndex:
     """The one public way to build an index (facade over the host GLIN)."""
+    from repro.core.engine import SpatialIndex
+    from repro.core.index import GLINConfig
+
     return SpatialIndex.build(dataset(name, n),
                               GLINConfig(piece_limitation=pl, **kw),
                               config=engine)

@@ -29,9 +29,7 @@ def kernel_rows(shapes=None, shards=KERNEL_SHARDS):
     """Roofline terms of the refinement pipeline from the analytic bytes/flops
     model in ``repro.kernels.refine.refine_cost`` — covering the compact
     kernel, the downstream exact-shape stage over the compacted survivors,
-    the staged compact+refine pipeline sum, the ONE-dispatch ``fused``
-    probe+compact+exact kernel (same work minus the staged pipeline's
-    inter-dispatch HBM round trips), AND the sharded variant
+    the staged compact+refine pipeline sum, AND the sharded variant
     (``sharded_refine_cost``: per-shard compact+refine plus the cross-shard
     survivor all-gather bytes), matching what
     ``core.distributed.build_glin_query_step`` actually executes."""
@@ -52,7 +50,6 @@ def kernel_rows(shapes=None, shards=KERNEL_SHARDS):
                                + stages["exact"]["bytes_accessed"]),
         }
         stages["compact+refine"] = pipeline
-        stages["fused"] = refine_cost("fused", q, n, budget, verts=verts)
         for s in shards:
             stages[f"sharded[{s}]"] = sharded_refine_cost(
                 q, n, budget, shards=s, verts=verts)
@@ -104,7 +101,7 @@ def main():
     ap.add_argument("--markdown", action="store_true")
     ap.add_argument("--kernels", action="store_true",
                     help="analytic roofline of the GLIN refinement kernels "
-                         "(count / compact / exact / compact+refine / fused)")
+                         "(count / compact / exact / compact+refine)")
     args = ap.parse_args()
     if args.kernels:
         for name, detail in kernel_rows():

@@ -42,6 +42,18 @@ def main() -> None:
     which = set((args.only or default).split(","))
     bench_jsons = {}
     print("name,us_per_call,derived")
+    if "sharded" in which:
+        # first, while this process has not imported JAX: a CPU rehearsal
+        # spawns fake-device children, which only a JAX-free parent may do;
+        # on a TPU host the bench runs in this process
+        from . import bench_sharded
+        if args.quick:
+            bench_jsons["sharded"] = bench_sharded.run(csv, n=20_000, q=48)
+        else:
+            bench_jsons["sharded"] = bench_sharded.run(csv, large=args.large)
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if "glin" in which:
         from . import bench_glin
         bench_glin.run(csv, large=args.large)
@@ -57,12 +69,6 @@ def main() -> None:
         else:
             bench_jsons["maintenance"] = bench_maintenance.run(
                 csv, large=args.large)
-    if "sharded" in which:
-        from . import bench_sharded
-        if args.quick:
-            bench_jsons["sharded"] = bench_sharded.run(csv, n=20_000, q=48)
-        else:
-            bench_jsons["sharded"] = bench_sharded.run(csv, large=args.large)
     if "serving" in which:
         from . import bench_serving
         bench_jsons["serving"] = bench_serving.run(csv, large=args.large,
@@ -76,6 +82,8 @@ def main() -> None:
         out_dir = pathlib.Path(args.bench_dir)
         out_dir.mkdir(parents=True, exist_ok=True)
         for name, payload in bench_jsons.items():
+            if payload is None:             # skipped (printed why)
+                continue
             path = out_dir / f"BENCH_{name}.json"
             path.write_text(json.dumps(payload, indent=1) + "\n")
             print(f"# wrote {path}")

@@ -22,6 +22,9 @@ from repro.core.distributed import build_glin_query_step, shard_glin_arrays
 
 def main() -> None:
     from repro.utils.compat import make_auto_mesh
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     mesh = make_auto_mesh((4, 2), ("data", "model"))
     print(f"[dist] mesh {dict(mesh.shape)} over {mesh.devices.size} devices")
 

@@ -22,6 +22,9 @@ import numpy as np
 from repro.core import (GLINConfig, QueryBatch, SpatialIndex, generate,
                         make_query_windows, relation_names)
 from repro.core.relations import RELATIONS
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. a synthetic "parks"-like dataset (100k convex polygons, metro clusters)
 gs = generate("cluster", 100_000, seed=0)

@@ -19,6 +19,7 @@ import numpy as np
 from repro.core import EngineConfig, GLINConfig, SpatialIndex, generate, \
     make_query_windows
 from repro.serve import SpatialQueryServer
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--batch-size", type=int, default=256)
     ap.add_argument("--selectivity", type=float, default=1e-4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"[serve] building index over {args.n} geometries ...")
     gs = generate("cluster", args.n, seed=0)

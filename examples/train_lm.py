@@ -32,6 +32,8 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = config_100m() if args.full else config_20m()
     print(f"[example] {cfg.name}: {cfg.param_count()/1e6:.1f}M params")

@@ -515,9 +515,7 @@ def _augment(s: GLINSnapshot, q_hi, q_lo):
 def _raw_query_keys(s: GLINSnapshot, windows: jax.Array, rel
                     ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Window quantization WITHOUT the augmentation rewrite: (zmin, ub=
-    zmax+1) hi/lo limbs. The fused kernel consumes these directly (its
-    suffix-min search runs in-kernel); ``query_keys`` layers ``_augment``
-    on top for the staged path."""
+    zmax+1) hi/lo limbs; ``query_keys`` layers ``_augment`` on top."""
     from .zorder import ZGrid
 
     grid = ZGrid(s.grid_x0, s.grid_y0, s.grid_cell)
@@ -573,9 +571,8 @@ def _exact_over(rel, windows: jax.Array, pods: VertexPods, rec: jax.Array,
     branch, so a batch whose survivors are all points/polylines never pays
     the widest ring's gather. Unselected lanes read real (clamped,
     in-bounds) data and are masked by the caller. Shared by every exact
-    stage — ``batch_query``'s three compaction paths and the dense path —
-    and mirrored inside the fused kernel (which runs the same ladder over
-    its VMEM-resident pod pool, per query tile)."""
+    stage — ``batch_query``'s compaction paths, the dense path and
+    ``batch_query_fused``."""
     off = pods.off[rec]
     nv = pods.nv[rec]
     kd = pods.kd[rec]
@@ -641,6 +638,28 @@ def _exact_refine_compacted(rel, windows: jax.Array, s: GLINSnapshot,
     return hits, counts
 
 
+def _compact_kernel_refine(rel, windows: jax.Array, s: GLINSnapshot,
+                           pods: VertexPods, start: jax.Array,
+                           end: jax.Array, kb: int, interpret: bool
+                           ) -> Tuple[jax.Array, jax.Array]:
+    """Probe runs -> the Pallas ``refine_compact`` kernel (capless: it
+    streams every slot tile) -> the shared exact stage. Overflow encodes the
+    TOTAL survivor count (``-(survivors) - 1``), so the caller can size its
+    budget ladder in one step."""
+    from repro.kernels import ops
+
+    if rel.prefilter_kind == "custom":
+        raise ValueError(
+            f"relation {rel.name!r} has a custom MBR prefilter; the compact "
+            "kernel cannot evaluate it — use compaction='scan'")
+    bounds = jnp.stack([start, end], axis=1)
+    slots, mbr_counts = ops.refine_compact(
+        rel.probe_window(windows, xp=jnp), bounds, s.slot_lmbr, s.slot_rmbr,
+        budget=kb, prefilter=rel.prefilter_kind, interpret=interpret)
+    hits, counts = _exact_refine_compacted(rel, windows, s, pods, slots)
+    return hits, jnp.where(mbr_counts > kb, -mbr_counts - 1, counts)
+
+
 @partial(jax.jit, static_argnames=("relation", "cap", "exact_budget",
                                    "compaction"))
 def batch_query(s: GLINSnapshot, windows: jax.Array, pods: VertexPods,
@@ -674,7 +693,12 @@ def batch_query(s: GLINSnapshot, windows: jax.Array, pods: VertexPods,
     * ``"pallas"`` — the fused ``refine_compact`` kernel: interval + leaf-MBR
       + record-MBR mask with in-VMEM prefix-sum compaction over the whole
       slot table; only (Q, budget) slot ids reach HBM, no ``cap``-sized
-      intermediate exists at all (``cap`` only bounds the dense fallback).
+      intermediate exists at all (``cap`` only bounds the dense path, and
+      a budget at or past the cap still runs two-stage). Any budget: the
+      kernel fills its output in 1024-column chunks. Always the compiled
+      kernel, on every backend.
+    * ``"interpret"`` — the same kernel body through Pallas interpret mode
+      (the CPU correctness path for the kernel).
     * ``"scan"``   — jnp reference semantics: (Q, cap) candidate window from
       the probe run, masked via the slot-aligned MBR tables, compacted with
       a stable cumsum + scatter (no sort). The CPU/interpret parity path.
@@ -682,7 +706,7 @@ def batch_query(s: GLINSnapshot, windows: jax.Array, pods: VertexPods,
       ``leaf_mbr[rec_leaf[slot]]`` / ``mbrs[recs[slot]]`` gathers (kept for
       the old-vs-new refinement benchmark).
     """
-    if compaction not in ("pallas", "scan", "sort"):
+    if compaction not in ("pallas", "interpret", "scan", "sort"):
         raise ValueError(f"unknown compaction {compaction!r}")
     rel = _device_relation(relation)
     start, end = batch_query_bounds(s, windows, relation)
@@ -694,25 +718,13 @@ def batch_query(s: GLINSnapshot, windows: jax.Array, pods: VertexPods,
     def exact_refine_compacted(slots):
         return _exact_refine_compacted(rel, windows, s, pods, slots)
 
-    if exact_budget and exact_budget < cap:
+    capless = compaction in ("pallas", "interpret")
+    if exact_budget > 0 and (capless or exact_budget < cap):
         kb = exact_budget
         probe_w = rel.probe_window(windows, xp=jnp)
-        if compaction == "pallas":
-            from repro.kernels import ops
-
-            if rel.prefilter_kind == "custom":
-                raise ValueError(
-                    f"relation {relation!r} has a custom MBR prefilter; the "
-                    "fused kernel cannot evaluate it — use compaction='scan'")
-            bounds = jnp.stack([start, end], axis=1)
-            slots, mbr_counts = ops.refine_compact(
-                probe_w, bounds, s.slot_lmbr, s.slot_rmbr, budget=kb,
-                prefilter=rel.prefilter_kind)
-            hits, counts = exact_refine_compacted(slots)
-            overflow = mbr_counts > kb
-            # overflow encodes the TOTAL survivor count (-(survivors) - 1),
-            # so the caller can size its budget ladder in one step
-            return hits, jnp.where(overflow, -mbr_counts - 1, counts)
+        if capless:
+            return _compact_kernel_refine(rel, windows, s, pods, start, end,
+                                          kb, compaction == "interpret")
 
         pos = start[:, None] + jnp.arange(cap, dtype=_I32)[None, :]
         valid = pos < jnp.minimum(end, start + cap)[:, None]
@@ -787,69 +799,24 @@ def batch_query(s: GLINSnapshot, windows: jax.Array, pods: VertexPods,
     return hits, counts
 
 
-def _fused_operands(s: GLINSnapshot) -> Tuple[jax.Array, ...]:
-    """Pack the snapshot's model tables into the fused kernel's VMEM-resident
-    column layouts (``kernels.refine.refine_fused_pallas`` documents them).
-    Empty tables (a one-leaf tree has no internal nodes; a non-augmenting
-    build has no pieces) pad to one zero row so every BlockSpec stays
-    non-degenerate — the kernel never reads them (depth loops self-terminate
-    on a done flag; ``augment=False`` skips the piecewise search)."""
-    zi = jnp.zeros((1,), _I32)
-    keys = jnp.stack([s.keys_hi, s.keys_lo], axis=1)
-    recs = s.recs[:, None]
-    leaf_i = jnp.stack([
-        s.leaf_start, s.leaf_dlo_hi, s.leaf_dlo_lo,
-        jnp.concatenate([s.leaf_k0_hi, zi]),
-        jnp.concatenate([s.leaf_k0_lo, zi]),
-    ], axis=1)
-    leaf_f = jnp.stack([
-        jnp.concatenate([s.leaf_slope, jnp.zeros((1,), jnp.float32)]),
-        jnp.concatenate([s.leaf_icpt, jnp.zeros((1,), jnp.float32)]),
-    ], axis=1)
-    if s.node_dlo_hi.shape[0]:
-        node_i = jnp.stack([s.node_dlo_hi, s.node_dlo_lo, s.node_fanout,
-                            s.node_child_base], axis=1)
-        node_f = s.node_scale[:, None]
-    else:
-        node_i = jnp.zeros((1, 4), _I32)
-        node_f = jnp.zeros((1, 1), jnp.float32)
-    codes = (s.child_codes[:, None] if s.child_codes.shape[0]
-             else jnp.zeros((1, 1), _I32))
-    if s.pw_zmax_hi.shape[0]:
-        pw = jnp.stack([s.pw_zmax_hi, s.pw_zmax_lo,
-                        s.pw_sufmin_hi, s.pw_sufmin_lo], axis=1)
-    else:
-        pw = jnp.zeros((1, 4), _I32)
-    return keys, recs, leaf_i, leaf_f, node_i, node_f, codes, pw
-
-
-@partial(jax.jit, static_argnames=("relation", "exact_budget", "mode"))
+@partial(jax.jit, static_argnames=("relation", "exact_budget"))
 def batch_query_fused(s: GLINSnapshot, windows: jax.Array, pods: VertexPods,
-                      relation: str = "contains", exact_budget: int = 256,
-                      mode: str = "reference"
+                      relation: str = "contains", exact_budget: int = 256
                       ) -> Tuple[jax.Array, jax.Array]:
-    """ONE-dispatch batched query: learned-index probe + MBR prefilter with
-    in-VMEM compaction + exact-shape refinement in a single kernel launch
-    (vs ``batch_query``'s probe -> compact -> exact sequence).
-
-    ``mode`` picks the execution vehicle, all three bit-identical to
-    ``batch_query(..., compaction="scan")``:
-
-    * ``"pallas"``    — the fused Pallas kernel (TPU; auto-interpret off-TPU).
-    * ``"interpret"`` — force the kernel through interpret mode (the CI
-      correctness path: same kernel body, CPU execution).
-    * ``"reference"`` — single-jit XLA composition of the same three stages
-      (probe bounds + cumsum/searchsorted compaction + shared exact stage).
-      Usable on any backend; what the CPU benchmarks time.
+    """Whole-table batched query as ONE XLA program: learned-index probe,
+    a capless mask over EVERY slot, cumsum + per-row searchsorted
+    compaction to ``(Q, exact_budget)`` survivors, and the exact stage.
+    Bit-identical to ``batch_query(..., compaction="scan")``; it holds a
+    ``(Q, N)`` mask, so it suits small stores (and is what the CPU
+    benchmarks time), while the TPU runs ``batch_query(...,
+    compaction="pallas")``, which streams the slot table through the
+    compact kernel in the same single program.
 
     Returns ``(hits (Q, budget) i32 [-1 padded], counts (Q,) i32)``. The
-    fused path is CAPLESS — the prefilter mask spans the whole slot table —
-    so a negative count always means budget overflow and encodes the total
-    MBR-survivor count ``-(survivors) - 1``
-    (``core.exec.OverflowLadder.on_fused_overflow`` sizes the retry budget
-    from it in one step, no disambiguating bounds probe needed)."""
-    if mode not in ("pallas", "interpret", "reference"):
-        raise ValueError(f"unknown fused mode {mode!r}")
+    path is CAPLESS, so a negative count always means budget overflow and
+    encodes the total MBR-survivor count ``-(survivors) - 1``
+    (``core.exec.OverflowLadder.on_capless_overflow`` sizes the retry
+    budget from it in one step, no disambiguating bounds probe needed)."""
     rel = _device_relation(relation)
     if rel.prefilter_kind == "custom":
         raise ValueError(
@@ -859,31 +826,12 @@ def batch_query_fused(s: GLINSnapshot, windows: jax.Array, pods: VertexPods,
         raise ValueError("the fused path is two-stage only: exact_budget "
                          "must be > 0")
     kb = exact_budget
-    probe_w = rel.probe_window(windows, xp=jnp)
-
-    if mode in ("pallas", "interpret"):
-        from repro.kernels import ops
-
-        zmin_hi, zmin_lo, ub_hi, ub_lo = _raw_query_keys(s, windows, rel)
-        qkeys = jnp.stack([zmin_hi, zmin_lo, ub_hi, ub_lo], axis=1)
-        pod_i = jnp.stack([pods.off, pods.nv, pods.kd, pods.bucket], axis=1)
-        return ops.refine_fused(
-            windows, probe_w, qkeys, *_fused_operands(s), pod_i, pods.pool,
-            s.slot_lmbr, s.slot_rmbr, budget=kb,
-            prefilter=rel.prefilter_kind,
-            predicate=lambda w, vv, nn, kk: rel.predicate(w, vv, nn, kk,
-                                                          xp=jnp),
-            augment=bool(rel.augment) and s.pw_zmax_hi.shape[0] > 0,
-            search_steps=s.search_steps, depth=s.depth,
-            num_buckets=pods.num_buckets,
-            interpret=True if mode == "interpret" else None)
-
-    # "reference": the same probe + capless mask + (Q, kb) compaction +
-    # exact stage as one XLA program. Compaction is cumsum + per-row
-    # searchsorted for the k-th survivor position — no (Q, N) scatter, which
-    # is what makes this composition beat the (Q, cap)-windowed scan path
-    # on CPU as well
     start, end = batch_query_bounds(s, windows, relation)
+
+    # compaction is cumsum + per-row searchsorted for the k-th survivor
+    # position — no (Q, N) scatter, which is what makes this composition
+    # beat the (Q, cap)-windowed scan path on CPU as well
+    probe_w = rel.probe_window(windows, xp=jnp)
     n = s.num_slots
     slot = jnp.arange(n, dtype=_I32)[None, :]
     in_run = (slot >= start[:, None]) & (slot < end[:, None])
@@ -1095,8 +1043,9 @@ def batch_knn_rank(windows: jax.Array, pods: VertexPods, hits: jax.Array,
     one rounding step more precise): ``impl="sort"`` is the XLA reference —
     a two-key ``lax.sort`` over ``[d2, ids]`` (plain ``lax.top_k`` cannot
     tie-break on ids); ``impl="pallas"`` routes the same selection through
-    the ``kernels.refine.knn_topk_pallas`` partial-sort kernel (TPU target,
-    interpret elsewhere; worthwhile once B is large), identical ordering.
+    the compiled ``kernels.refine.knn_topk_pallas`` partial-sort kernel (TPU
+    target; worthwhile once B is large), identical ordering, and
+    ``impl="interpret"`` runs that kernel through interpret mode.
 
     ``radius`` ((Q,) f32) is each point's OWN probe radius this rung — the
     caller probes every still-undone point in one dispatch at per-point
@@ -1139,10 +1088,10 @@ def batch_knn_rank(windows: jax.Array, pods: VertexPods, hits: jax.Array,
         d2 = jnp.concatenate([d2, jnp.full((q, padw), inf)], axis=1)
         ids = jnp.concatenate([ids, jnp.full((q, padw), _ID_PAD, _I32)],
                               axis=1)
-    if impl == "pallas":
+    if impl in ("pallas", "interpret"):
         from repro.kernels import ops
 
-        d2k, idk = ops.knn_topk(d2, ids, k=k)
+        d2k, idk = ops.knn_topk(d2, ids, k=k, interpret=impl == "interpret")
     else:
         d2s, idss = jax.lax.sort([d2, ids], num_keys=2)
         d2k, idk = d2s[:, :k], idss[:, :k]

@@ -180,8 +180,9 @@ def build_glin_query_step(mesh: Mesh, relation: str = "intersects",
     path, so escalation policy lives in exactly one place.
 
     ``compaction`` picks the stage-1 implementation: ``"scan"`` (the jnp
-    cumsum+scatter reference — the CPU path) or ``"pallas"`` (the fused
-    ``refine_compact`` kernel on TPU). ``exact_budget == 0`` is the legacy
+    cumsum+scatter reference — the CPU path), ``"pallas"`` (the compiled
+    ``refine_compact`` kernel, TPU) or ``"interpret"`` (that kernel through
+    Pallas interpret mode). ``exact_budget == 0`` is the legacy
     dense single-stage path (kept as the sharded benchmark baseline).
 
     ``max_width`` (a power of two) is the static top of the vertex-width
@@ -194,10 +195,10 @@ def build_glin_query_step(mesh: Mesh, relation: str = "intersects",
     if not rel.device_native:
         raise ValueError(f"relation {relation!r} is not device-native; shard "
                          f"its base relation {rel.base_name()!r} instead")
-    if compaction not in ("scan", "pallas"):
+    if compaction not in ("scan", "pallas", "interpret"):
         raise ValueError(f"unsupported sharded compaction {compaction!r} "
-                         "(use 'scan' or 'pallas')")
-    if exact_budget and compaction == "pallas" \
+                         "(use 'scan', 'pallas' or 'interpret')")
+    if exact_budget and compaction in ("pallas", "interpret") \
             and rel.prefilter_kind == "custom":
         raise ValueError(
             f"relation {relation!r} has a custom MBR prefilter; the fused "
@@ -205,7 +206,11 @@ def build_glin_query_step(mesh: Mesh, relation: str = "intersects",
     if max_width < 1 or (max_width & (max_width - 1)):
         raise ValueError(f"max_width must be a power of two, got {max_width}")
     daxes = _data_axes(mesh)
-    kb = exact_budget if 0 < exact_budget < cap else 0
+    # the compact kernel scans every local slot whatever the cap, so its
+    # budget is used even at or past the cap
+    capless = compaction in ("pallas", "interpret")
+    kb = (exact_budget if exact_budget > 0
+          and (capless or exact_budget < cap) else 0)
     nbuckets = max_width.bit_length()      # widths 1, 2, ..., max_width
 
     table_spec = {k: P(daxes) for k in TABLE_KEYS}
@@ -283,13 +288,14 @@ def build_glin_query_step(mesh: Mesh, relation: str = "intersects",
             return hits, fmask.sum(axis=1).astype(_I32)
 
         if kb:
-            if compaction == "pallas":
+            if compaction in ("pallas", "interpret"):
                 from repro.kernels import ops
 
                 bounds = jnp.stack([lstart, lend], axis=1)
                 slots, surv = ops.refine_compact(
                     probe_w, bounds, table["lmbrs"], table["mbrs"],
-                    budget=kb, prefilter=rel.prefilter_kind)
+                    budget=kb, prefilter=rel.prefilter_kind,
+                    interpret=compaction == "interpret")
                 overflow = surv > kb
             else:
                 pos = lstart[:, None] + jnp.arange(cap, dtype=_I32)[None, :]
@@ -351,16 +357,19 @@ def build_glin_query_step(mesh: Mesh, relation: str = "intersects",
     return step, in_shardings, out_shardings
 
 
-def build_glin_knn_step(mesh: Mesh, relation: str, k: int, cap: int = 512,
+def build_glin_knn_step(mesh: Mesh, k: int, cap: int = 512,
                         exact_budget: int = 0, compaction: str = "scan",
                         max_width: int = 64):
     """Device-complete sharded kNN: shard-local top-k + cross-shard k-merge.
 
     Returns (step_fn, in_shardings, out_shardings) like
-    :func:`build_glin_query_step`; ``relation`` must be a bound
-    ``dwithin:<r>`` (the probe radius rides on ``rel.probe_pad``).
+    :func:`build_glin_query_step`. ``windows`` are the degenerate point
+    windows and ``radii`` (Q,) f32 each point's probe radius: every row
+    probes ``intersects`` on its point inflated by its own radius (the
+    ``dwithin:<r>`` probe geometry, per row), so points at different radii
+    share one batch and one compiled program.
 
-    step(snapshot, windows, table) -> (ids, dists, counts):
+    step(snapshot, windows, radii, table) -> (ids, dists, counts):
       ids   (Q, k) int32   — merged global record ids, ascending
                              (distance, id), -1 past the candidate count
       dists (Q, k) float32 — matching exact point-to-geometry distances
@@ -374,40 +383,41 @@ def build_glin_knn_step(mesh: Mesh, relation: str, k: int, cap: int = 512,
     then gathers exact SQUARED distances from its local vertex pool at the
     widest surviving width bucket and partial-sorts its own block to a
     local ``(Q, k)`` top-k by ascending ``(d2, global id)`` — candidate
-    sets never leave their shard. The cross-shard merge is ONE collective:
-    reshaping the ``(Q, shards, k)`` output across the data axes
-    all-gathers every shard's block, and a replicated two-key sort takes
-    the global k — ``q * shards * (k*8 + 4)`` bytes on the wire (the
+    sets never leave their shard. The cross-shard merge is ONE collective
+    inside the shard_map: an all-gather of every shard's ``(Q, k)`` block
+    over the data axes, then a replicated two-key sort takes the global
+    k — ``q * shards * (k*8 + 4)`` bytes on the wire (the
     collective term of ``kernels.refine.sharded_knn_cost``), independent of
     the candidate counts.
 
-    The within-radius counts are compared in squared form — exactly the
-    dwithin predicate's test — so the caller's settlement rule (done once
-    the summed counts reach k) never over-counts. Snapshot records only: no
+    The within-radius counts compare squared distances with the squared
+    radius — exactly the dwithin predicate's test for the power-of-two
+    radii the caller uses — so the settlement rule (done once the summed
+    counts reach k) never over-counts. Snapshot records only: no
     tombstone/delta merge here — the caller republishes a stale snapshot
     first (the sharded k-merge exactness contract)."""
-    rel = get_relation(relation)
-    if not relation.startswith("dwithin:") or rel.parametric:
-        raise ValueError(f"knn step needs a bound dwithin relation, got "
-                         f"{relation!r}")
-    if compaction not in ("scan", "pallas"):
+    rel = get_relation("intersects")
+    if compaction not in ("scan", "pallas", "interpret"):
         raise ValueError(f"unsupported sharded compaction {compaction!r} "
-                         "(use 'scan' or 'pallas')")
+                         "(use 'scan', 'pallas' or 'interpret')")
     if max_width < 1 or (max_width & (max_width - 1)):
         raise ValueError(f"max_width must be a power of two, got {max_width}")
     if k < 1:
         raise ValueError(f"k must be >= 1, got {k}")
-    r2 = jnp.float32(float(rel.probe_pad) ** 2)
     daxes = _data_axes(mesh)
-    kb = exact_budget if 0 < exact_budget < cap else 0
+    # the compact kernel scans every local slot whatever the cap, so its
+    # budget is used even at or past the cap
+    capless = compaction in ("pallas", "interpret")
+    kb = (exact_budget if exact_budget > 0
+          and (capless or exact_budget < cap) else 0)
     nbuckets = max_width.bit_length()
     id_pad = jnp.int32(2**31 - 1)     # sorts after every real record id
 
     table_spec = {kk: P(daxes) for kk in TABLE_KEYS}
-    in_specs = (GLIN_MODEL_SPEC, P("model"), table_spec)
-    out_specs = (P("model", daxes), P("model", daxes), P("model", daxes))
+    in_specs = (GLIN_MODEL_SPEC, P("model"), P("model"), table_spec)
+    out_specs = (P("model"), P("model"), P("model", daxes))
 
-    def local_step(snapshot: GLINSnapshot, windows, table):
+    def local_step(snapshot: GLINSnapshot, windows, radii, table):
         # candidate selection: the window step's per-shard slot compaction,
         # verbatim (see build_glin_query_step for the commentary)
         shard_id = jax.lax.axis_index(daxes[0])
@@ -417,8 +427,10 @@ def build_glin_knn_step(mesh: Mesh, relation: str, k: int, cap: int = 512,
         local_n = table["keys_hi"].shape[0]
         offset = shard_id.astype(_I32) * local_n
 
-        zmin_hi, zmin_lo, ub_hi, ub_lo = query_keys(snapshot, windows,
-                                                    relation)
+        # per-row probe square: the point inflated by its own radius
+        probe_w = windows + jnp.stack([-radii, -radii, radii, radii], axis=1)
+        zmin_hi, zmin_lo, ub_hi, ub_lo = query_keys(snapshot, probe_w,
+                                                    "intersects")
 
         def local_lb(q_hi, q_lo):
             lo_g, hi_g = model_window(snapshot, q_hi, q_lo)
@@ -431,23 +443,23 @@ def build_glin_knn_step(mesh: Mesh, relation: str, k: int, cap: int = 512,
         lstart = local_lb(zmin_hi, zmin_lo)
         lend = local_lb(ub_hi, ub_lo)
         qn = windows.shape[0]
-        probe_w = rel.probe_window(windows, xp=jnp)
 
         if kb:
-            if compaction == "pallas":
+            if compaction in ("pallas", "interpret"):
                 from repro.kernels import ops
 
                 bounds = jnp.stack([lstart, lend], axis=1)
                 slots, surv = ops.refine_compact(
                     probe_w, bounds, table["lmbrs"], table["mbrs"],
-                    budget=kb, prefilter=rel.prefilter_kind)
+                    budget=kb, prefilter=rel.prefilter_kind,
+                    interpret=compaction == "interpret")
                 overflow = surv > kb
             else:
                 pos = lstart[:, None] + jnp.arange(cap, dtype=_I32)[None, :]
                 valid = pos < jnp.minimum(lend, lstart + cap)[:, None]
                 posc = jnp.minimum(pos, local_n - 1)
                 rmbr = table["mbrs"][posc]
-                rec_ok = rel.mbr_prefilter(rmbr, windows[:, None, :], xp=jnp)
+                rec_ok = rel.mbr_prefilter(rmbr, probe_w[:, None, :], xp=jnp)
                 mask = valid & rec_ok
                 m32 = mask.astype(_I32)
                 excl = jnp.cumsum(m32, axis=1) - m32
@@ -467,7 +479,7 @@ def build_glin_knn_step(mesh: Mesh, relation: str, k: int, cap: int = 512,
             valid = pos < jnp.minimum(lend, lstart + cap)[:, None]
             posc = jnp.minimum(pos, local_n - 1)
             rmbr = table["mbrs"][posc]
-            rec_ok = rel.mbr_prefilter(rmbr, windows[:, None, :], xp=jnp)
+            rec_ok = rel.mbr_prefilter(rmbr, probe_w[:, None, :], xp=jnp)
             slots = jnp.where(valid & rec_ok, posc, -1)
             runlen = lend - lstart
             overflow = runlen > cap
@@ -503,7 +515,7 @@ def build_glin_knn_step(mesh: Mesh, relation: str, k: int, cap: int = 512,
         inf = jnp.float32(jnp.inf)
         d2 = jnp.where(ok, d2, inf)
         idv = jnp.where(ok, rec, id_pad)
-        within = (d2 <= r2).sum(axis=1).astype(_I32)
+        within = (d2 <= (radii * radii)[:, None]).sum(axis=1).astype(_I32)
         counts = jnp.where(overflow, -surv.astype(_I32) - 1, within)
         if d2.shape[1] < k:               # k > budget: pad the sort columns
             padw = k - d2.shape[1]
@@ -511,28 +523,22 @@ def build_glin_knn_step(mesh: Mesh, relation: str, k: int, cap: int = 512,
             idv = jnp.concatenate(
                 [idv, jnp.full((qn, padw), id_pad, _I32)], axis=1)
         d2s, idss = jax.lax.sort([d2, idv], num_keys=2)
-        return (d2s[:, None, :k], idss[:, None, :k], counts[:, None])
-
-    local = compat_shard_map(local_step, mesh, in_specs, out_specs)
-    nshards = 1
-    for a in daxes:
-        nshards *= mesh.shape[a]
-
-    def step(snapshot, windows, table):
-        d2b, idb, counts = local(snapshot, windows, table)
-        q = windows.shape[0]
-        # cross-shard k-merge: flattening the shard axis all-gathers the
-        # (shards, k) blocks over the data axes (ONE collective) and the
-        # replicated two-key sort takes the global k
-        d2s, idss = jax.lax.sort(
-            [d2b.reshape(q, nshards * k), idb.reshape(q, nshards * k)],
-            num_keys=2)
-        d2k, idk = d2s[:, :k], idss[:, :k]
+        # cross-shard k-merge, inside the shard_map: ONE all-gather of the
+        # (k) blocks over the data axes and a replicated two-key sort.
+        # Left to the SPMD partitioner (a sort of the sharded block), the
+        # TPU compiler overflowed its stack resharding the sort operand
+        d2g = jax.lax.all_gather(d2s[:, :k], daxes, axis=1, tiled=True)
+        idg = jax.lax.all_gather(idss[:, :k], daxes, axis=1, tiled=True)
+        d2m, idm = jax.lax.sort([d2g, idg], num_keys=2)
+        d2k, idk = d2m[:, :k], idm[:, :k]
         dists = jnp.sqrt(jnp.maximum(d2k, 0.0))
-        return jnp.where(jnp.isinf(d2k), -1, idk), dists, counts
+        return (jnp.where(jnp.isinf(d2k), -1, idk), dists, counts[:, None])
+
+    step = compat_shard_map(local_step, mesh, in_specs, out_specs)
 
     in_shardings = (
         NamedSharding(mesh, GLIN_MODEL_SPEC),
+        NamedSharding(mesh, P("model")),
         NamedSharding(mesh, P("model")),
         {kk: NamedSharding(mesh, s) for kk, s in table_spec.items()},
     )

@@ -91,22 +91,22 @@ class EngineConfig:
                                       # stage 1 masks + compacts, stage 2
                                       # exact-checks at most this many
                                       # candidates per query
-    compaction: Optional[str] = None  # stage-1 impl: "pallas" (fused kernel),
+    compaction: Optional[str] = None  # stage-1 impl: "pallas" (compiled
+                                      # compact kernel), "interpret" (that
+                                      # kernel in Pallas interpret mode),
                                       # "scan" (jnp reference), "sort"
                                       # (legacy argsort); None = pallas on
                                       # TPU, scan elsewhere
-    fusion: Optional[str] = None      # one-kernel probe+compact+refine:
-                                      # "pallas" (fused kernel; interpret
-                                      # off-TPU), "interpret" (force the
-                                      # kernel through interpret mode — CI
-                                      # correctness), "reference" (single-jit
-                                      # XLA composition, any backend), "off";
-                                      # None = auto: pallas on TPU, off
-                                      # elsewhere. Custom-prefilter relations,
-                                      # budgets outside (0, MAX_COMPACT_
-                                      # BUDGET] and stores past the kernel's
-                                      # VMEM envelope fall back to the staged
-                                      # pipeline automatically
+    fusion: Optional[str] = None      # "reference": refine with the
+                                      # capless whole-table XLA composition
+                                      # (batch_query_fused, O(Q·N) memory —
+                                      # small stores, CPU timing); "off" or
+                                      # None: the staged batch_query, whose
+                                      # "pallas" compaction already runs
+                                      # probe + kernel + exact stage as one
+                                      # jitted program. Custom-prefilter
+                                      # relations and dense escalations run
+                                      # staged either way
     delta_device_min: int = 64        # added-set size at which device+delta
                                       # patching moves from the host loop to
                                       # the device-resident DeltaTable
@@ -123,9 +123,11 @@ class EngineConfig:
                                       # is the correctness backstop: a bad
                                       # seed costs extra rungs, never hits
     knn_topk: Optional[str] = None    # device top-k impl: "sort" (two-key
-                                      # lax.sort reference) or "pallas" (the
+                                      # lax.sort reference), "pallas" (the
                                       # k-round partial-selection kernel —
-                                      # wins when k << candidate columns);
+                                      # wins when k << candidate columns) or
+                                      # "interpret" (that kernel in interpret
+                                      # mode);
                                       # None = auto: pallas on TPU, sort
                                       # elsewhere. Both obey the (distance,
                                       # id) tie-break contract
@@ -382,12 +384,17 @@ class SpatialIndex:
                     "wall_ms": 0.0, "queries": 0, "survivors": 0,
                     "escalations": 0, "dispatches": 0, "delta_added": 0,
                     "delta_tombstoned": 0, "rungs": 0, "seed_hits": 0,
-                    "merge_bytes": 0, "rung_hist": []})
+                    "merge_bytes": 0, "rung_hist": [], "notes": []})
                 ent["calls"] += 1
                 ent["wall_ms"] += ss.wall_ms
                 # the executing impl may differ per call (staged vs fused
                 # refine share the "refine" label): report the latest
                 ent["impl"] = ss.impl
+                # every distinct note (implementation picked, fallbacks
+                # taken): a fallback in one call must not hide behind a
+                # later call's note
+                if ss.note and ss.note not in ent["notes"]:
+                    ent["notes"].append(ss.note)
                 if ss.skipped:
                     ent["skipped"] += 1
                     continue
@@ -819,66 +826,38 @@ class SpatialIndex:
             self._replica_places[rep] = ent
         return ent[1], ent[2]
 
-    def _compaction(self, base_relation: str,
-                    budget: Optional[int] = None) -> str:
-        """Stage-1 refinement implementation for ``batch_query``: the fused
-        Pallas kernel on TPU, the jnp reference elsewhere (interpret-mode
-        Pallas is a correctness tool, not a CPU execution path), and the jnp
-        reference whenever the relation's MBR prefilter has no static kernel
-        shape (``prefilter_kind == "custom"``). ``budget`` is the budget the
-        call will actually use (the overflow ladder grows it past the
-        configured default)."""
+    def _compaction(self, base_relation: str) -> str:
+        """Stage-1 refinement implementation for ``batch_query``: the Pallas
+        compact kernel on TPU, the jnp reference elsewhere (interpret-mode
+        Pallas is a correctness tool, taken only when configured by name),
+        and the jnp reference whenever the relation's MBR prefilter has no
+        static kernel shape (``prefilter_kind == "custom"``). The kernel
+        takes any budget, so the choice does not change along the overflow
+        ladder."""
         mode = self.config.compaction
         if mode is None:
             mode = "pallas" if jax.default_backend() == "tpu" else "scan"
-        if mode == "pallas":
-            from repro.kernels.refine import MAX_COMPACT_BUDGET
-
-            b = self.config.exact_budget if budget is None else budget
-            if (get_relation(base_relation).prefilter_kind == "custom"
-                    or b > MAX_COMPACT_BUDGET):
-                # custom MBR prefilters have no static kernel shape, and
-                # budgets past the VMEM bound cannot host the one-hot
-                # scatter block — both take the jnp reference
-                mode = "scan"
+        if (mode in ("pallas", "interpret")
+                and get_relation(base_relation).prefilter_kind == "custom"):
+            mode = "scan"   # no static kernel shape for a custom prefilter
         return mode
 
-    def _fusion_mode(self, base_relation: str, budget: Optional[int] = None,
-                     snap: Optional[GLINSnapshot] = None,
-                     pods=None) -> Optional[str]:
+    def _fusion_mode(self, base_relation: str,
+                     budget: Optional[int] = None) -> Optional[str]:
         """Resolve ``EngineConfig.fusion`` to a ``batch_query_fused`` mode,
-        or ``None`` when the fused one-dispatch path cannot (or should not)
-        serve the call and the staged pipeline must: fusion off, a
-        custom-prefilter relation (no static kernel mask shape), a budget
-        outside the two-stage envelope ``(0, MAX_COMPACT_BUDGET]``, or —
-        for the kernel modes, when ``snap``/``pods`` are at hand — a store
-        whose resident tables outgrow ``FUSED_VMEM_LIMIT``."""
-        from repro.kernels.refine import (FUSED_VMEM_LIMIT,
-                                          MAX_COMPACT_BUDGET,
-                                          fused_vmem_bytes)
-
+        or ``None`` when the staged pipeline serves the call: fusion off, a
+        custom-prefilter relation (no static mask shape), or a dense
+        escalation (budget 0)."""
         mode = self.config.fusion
-        if mode is None:
-            mode = ("pallas" if jax.default_backend() == "tpu" else "off")
-        if mode == "off":
+        if mode is None or mode == "off":
             return None
-        if mode not in ("pallas", "interpret", "reference"):
-            raise ValueError(f"unknown fusion mode {mode!r}")
+        if mode != "reference":
+            raise ValueError(f"unknown fusion mode {mode!r} "
+                             "(use 'reference' or 'off')")
         if get_relation(base_relation).prefilter_kind == "custom":
             return None
         b = self.config.exact_budget if budget is None else budget
-        if not 0 < b <= MAX_COMPACT_BUDGET:
-            return None
-        if (mode in ("pallas", "interpret") and snap is not None
-                and pods is not None
-                and fused_vmem_bytes(
-                    snap.num_slots, snap.num_leaves,
-                    snap.node_dlo_hi.shape[0], snap.child_codes.shape[0],
-                    snap.pw_zmax_hi.shape[0], pods.num_records,
-                    pods.pool.shape[0], b, pods.max_width)
-                > FUSED_VMEM_LIMIT):
-            return None
-        return mode
+        return mode if b > 0 else None
 
     # ---------------------------------------------------------------- sharded
     def _sharded_available(self) -> bool:
@@ -977,18 +956,18 @@ class SpatialIndex:
             self._shard_steps[key] = fn
         return fn
 
-    def _sharded_knn_step(self, relation: str, k: int, cap: int, budget: int,
+    def _sharded_knn_step(self, k: int, cap: int, budget: int,
                           compaction: str, max_width: int):
         """Jit cache for the sharded knn probe+rank+k-merge step, keyed like
-        ``_sharded_step`` plus k; pow2-snapped radii keep the relation-string
-        key space (and thus compilations) bounded."""
-        key = ("knn", relation, k, cap, budget, compaction, max_width)
+        ``_sharded_step`` with k in place of the relation: the radii are a
+        traced input, so every radius shares one compilation."""
+        key = ("knn", k, cap, budget, compaction, max_width)
         fn = self._shard_steps.get(key)
         if fn is None:
             from .distributed import build_glin_knn_step
 
             step, in_sh, out_sh = build_glin_knn_step(
-                self.config.mesh, relation, k, cap=cap, exact_budget=budget,
+                self.config.mesh, k, cap=cap, exact_budget=budget,
                 compaction=compaction, max_width=max_width)
             fn = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh)
             self._shard_steps[key] = fn
@@ -1078,7 +1057,7 @@ class SpatialIndex:
                              reason, delta)
 
         fused = self._fusion_mode(base.name) is not None
-        fnote = "; fused one-kernel refine" if fused else ""
+        fnote = "; fused whole-table refine" if fused else ""
 
         def device(reason):
             return QueryPlan("device", "window", rel.name, base.name, stale,

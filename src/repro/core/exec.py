@@ -5,14 +5,14 @@ GLIN's query path is ONE pipeline regardless of where it runs::
     probe -> compact -> refine -> delta-patch -> complement-finish
                                                        (knn: -> knn-rank)
 
-What differs per backend is which *implementation* serves each stage and
-how many adjacent stages it fuses: the host loop walks the mutable tree one
-window at a time (probe+compact+refine in one pass), the jitted device
-``batch_query`` composes the same three stages as THREE device dispatches
-(probe, compact kernel, exact gather+check), ``batch_query_fused`` collapses
-them into ONE (:class:`FusedDeviceStage`, selected by
-``EngineConfig.fusion``), and the sharded step runs them per record shard
-under a mesh. Delta patching and
+What differs per backend is which *implementation* serves each stage:
+the host loop walks the mutable tree one window at a time
+(probe+compact+refine in one pass), the jitted device ``batch_query`` runs
+the three stages as ONE program (probe, compaction — the Pallas compact
+kernel on TPU — and the exact gather+check), ``batch_query_fused`` is the
+whole-table XLA composition of the same stages
+(:class:`FusedDeviceStage`, ``EngineConfig.fusion="reference"``), and the
+sharded step runs them per record shard under a mesh. Delta patching and
 complement finishing are backend-independent — they operate on id lists
 against state frozen under the facade lock — so exactly ONE implementation
 of each exists, here.
@@ -33,14 +33,14 @@ negative counts: ``-(run length) - 1`` when a query's candidate run outgrew
 MBR survivors outgrew ``exact_budget``. The ladder jumps the cap straight
 to a sufficient power of two (a cheap bounds-only probe tells the two
 overflows apart on the single-device path; the sharded step encodes the
-exact local need), grows the budget geometrically past the true survivor
-count, and escalates to the single-stage dense path only once the needed
-budget exceeds ``MAX_COMPACT_BUDGET`` (or the cap — two-stage would no
-longer shrink anything). One special case: the Pallas compact kernel and
-the fused one-dispatch path scan the full local run (they are capless), so
-with a budget active their overflow is ALWAYS the budget, even when
-survivors exceed the cap — the fused retry therefore needs no
-disambiguating bounds probe (:meth:`OverflowLadder.on_fused_overflow`).
+exact local need) and grows the budget geometrically past the true
+survivor count. CAPPED compaction (jnp ``scan``/``sort``, windowed to the
+cap) escalates to the single-stage dense path once the needed budget
+exceeds ``CAPPED_MAX_BUDGET`` or the cap. CAPLESS compaction (the Pallas
+compact kernel and the whole-table fused path scan every slot) overflows
+only on the budget — no bounds probe — and its budget grows to
+``max_cap``: the dense path would need a cap as long as the probe run,
+which on a deployment-size store is far past ``max_cap``.
 
 **Locking contract** (unchanged from the monolithic backends, now stated
 once): the host and sharded refine stages run under the facade lock — they
@@ -51,12 +51,12 @@ its device compute OUTSIDE it. Delta patching and complement finishing
 always run lock-free on the frozen copies, so their answers are exact at
 the frozen epoch no matter how writers interleave.
 
-**Dispatch telemetry**: every stage counts the device dispatches it issued
-into ``StageStats.dispatches`` (a staged two-stage attempt is 3 — probe,
-compact, exact; a dense attempt 2; a fused attempt 1; each disambiguating
-bounds probe adds 1). The counter is how the 3 -> 1 collapse of the fused
-path is *asserted*, not just assumed — a regression that silently re-splits
-the pipeline shows up in ``stats()["stages"]`` and ``explain()``.
+**Telemetry**: every stage counts the jitted programs it launched into
+``StageStats.dispatches`` (one per ladder attempt — ``batch_query`` and the
+sharded steps are single programs — plus one per disambiguating bounds
+probe and one per kNN rank), and names in ``StageStats.note`` each
+implementation its attempts ran (``compaction=pallas``, ``dense``, ...), so
+a fallback in any attempt shows in ``stats()["stages"]`` and ``explain()``.
 """
 from __future__ import annotations
 
@@ -100,9 +100,9 @@ class StageStats:
     does not produce ids, e.g. a skipped patch); ``escalations`` counts
     overflow-ladder retries; ``cap``/``budget`` are the settled ladder values
     a refine stage ended on (budget 0 = single-stage dense, -1 = n/a);
-    ``dispatches`` counts device dispatches issued (staged two-stage attempt
-    = 3, dense = 2, fused = 1, +1 per disambiguating bounds probe — 0 for
-    host/shared stages that launch no device work)."""
+    ``dispatches`` counts jitted programs launched (one per ladder attempt,
+    +1 per disambiguating bounds probe or kNN rank — 0 for host/shared
+    stages that launch no device work)."""
 
     stage: str                       # primary canonical stage name
     impl: str                        # "host" | "device" | "fused" |
@@ -125,6 +125,13 @@ class StageStats:
     seed_hits: int = 0               # points settled at their seeded radius
     seed_radius: float = 0.0         # median pow2-snapped seed radius
     merge_bytes: int = 0             # cross-shard k-merge collective bytes
+
+    def add_note(self, text: str) -> None:
+        """Append ``text`` to the note unless it is already there: a stage
+        whose ladder attempts ran different implementations names each."""
+        parts = self.note.split("; ") if self.note else []
+        if text not in parts:
+            self.note = "; ".join(parts + [text])
 
     def asdict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -164,6 +171,12 @@ def _total(ids: Optional[List[np.ndarray]]) -> int:
 
 
 # -------------------------------------------------------------- overflow ladder
+# budget ceiling of the CAPPED compactions (jnp "scan"/"sort", windowed to
+# the cap) before the ladder drops to the dense path: there a dense retry
+# only re-checks cheap predicates over the same (Q, cap) window
+CAPPED_MAX_BUDGET = 1024
+
+
 class OverflowLadder:
     """THE cap/budget escalation policy, shared by every refine
     implementation (single-device and sharded). See the module docstring for
@@ -171,30 +184,38 @@ class OverflowLadder:
 
     Holds the adaptive state for one query's retries; the settled ``cap`` is
     max-merged back into the facade by the refine stage so the ladder is
-    walked once per workload, not once per call."""
+    walked once per workload, not once per call.
 
-    def __init__(self, config, cap: int, max_budget: Optional[int] = None):
-        from repro.kernels.refine import MAX_COMPACT_BUDGET
+    ``capless`` marks a compaction that scans every slot whatever the cap
+    (the Pallas compact kernel, the whole-table reference): its budget is
+    used even when it reaches the cap, and grows up to ``max_cap`` — the
+    dense fallback would need a cap as long as the probe run, which on a
+    deployment-size store is far past ``max_cap``."""
 
+    def __init__(self, config, cap: int, max_budget: Optional[int] = None,
+                 capless: bool = False):
         self.config = config
         self.cap = int(cap)
         self.budget = int(config.exact_budget)
+        self.capless = bool(capless)
         # budget-growth ceiling before the ladder falls back to the dense
-        # single-stage path. Window refines keep the Pallas VMEM bound (a
-        # dense retry only re-checks cheap predicates); knn raises it to
-        # max_cap because the rank's exact-distance work scales with the
-        # hit-matrix WIDTH — scan compaction at a large budget is far
-        # cheaper than ranking a dense (Q, cap) matrix every rung.
-        self.max_budget = (MAX_COMPACT_BUDGET if max_budget is None
-                           else int(max_budget))
+        # single-stage path: max_cap for capless compaction, the capped
+        # ceiling otherwise. knn raises it to max_cap either way because
+        # the rank's exact-distance work scales with the hit-matrix WIDTH —
+        # scan compaction at a large budget is far cheaper than ranking a
+        # dense (Q, cap) matrix every rung.
+        if max_budget is None:
+            max_budget = config.max_cap if capless else CAPPED_MAX_BUDGET
+        self.max_budget = int(max_budget)
         self.escalations = 0
 
     @property
     def use_budget(self) -> int:
-        """The budget the next call actually uses: two-stage refinement only
-        pays for itself while the budget is positive AND below the cap."""
+        """The budget the next call actually uses: capped two-stage
+        refinement only pays for itself while the budget is positive AND
+        below the cap; capless compaction takes any positive budget."""
         b = self.budget
-        return b if 0 < b < self.cap else 0
+        return b if b > 0 and (self.capless or b < self.cap) else 0
 
     def grow_cap(self, need: int) -> None:
         cfg = self.config
@@ -206,58 +227,67 @@ class OverflowLadder:
         self.cap = min(max(self.cap * 2, 1 << (need - 1).bit_length()),
                        cfg.max_cap)
 
+    def set_budget(self, target: int) -> None:
+        """Take ``target`` as the next budget, or zero it (the dense path)
+        once it passes ``max_budget`` — or, for capped compaction, the cap
+        (two-stage would no longer shrink anything)."""
+        self.budget = (0 if target > self.max_budget
+                       or (not self.capless and target >= self.cap)
+                       else int(target))
+
     def grow_budget(self, use_budget: int, survivors: int) -> None:
         """Budget overflow: the negative-count encoding carries the TRUE
         survivor count, so the budget grows geometrically straight past it
-        (re-running compaction) and only falls back to the single-stage
-        dense path (budget 0) once the needed budget exceeds
-        ``max_budget`` (``MAX_COMPACT_BUDGET`` unless the caller raised it;
-        ``engine._compaction`` already routes budgets past the Pallas VMEM
-        bound to the jnp scan reference) or the cap."""
-        target = max(use_budget * 2,
-                     1 << max(survivors - 1, 0).bit_length())
-        self.budget = (0 if target > self.max_budget or target >= self.cap
-                       else target)
+        (re-running compaction) up to ``max_budget``."""
+        self.set_budget(max(use_budget * 2,
+                            1 << max(survivors - 1, 0).bit_length()))
 
     def on_device_overflow(self, counts: np.ndarray, use_budget: int,
-                           probe_bounds, batch_len: int) -> None:
-        """Single-device retry: the overflow signal conflates run-length >
-        cap with survivors > budget; ``probe_bounds`` (a cheap bounds-only
-        probe) tells them apart, so the cap jumps straight to sufficiency —
-        keeping the LOGICAL budget (one the old cap disabled because
-        ``budget >= cap`` comes back into play once the cap outgrows it)."""
+                           probe_bounds, batch_len: int) -> bool:
+        """Single-device retry: the overflow signal of capped compaction
+        conflates run-length > cap with survivors > budget; ``probe_bounds``
+        (a cheap bounds-only probe) tells them apart, so the cap jumps
+        straight to sufficiency — keeping the LOGICAL budget (one the old
+        cap disabled because ``budget >= cap`` comes back into play once the
+        cap outgrows it). Capless compaction with a budget active overflows
+        ONLY on the budget, so no probe runs. Returns whether the bounds
+        probe ran."""
+        if use_budget and self.capless:
+            self.on_capless_overflow(counts, use_budget)
+            return False
         self.escalations += 1
         start, end = probe_bounds()
         need = int(np.max(np.asarray(end - start))) if batch_len else 0
         if need > self.cap:
             self.grow_cap(need)
-            return
+            return True
         if not use_budget:
             raise AssertionError(
                 "single-stage overflow with run <= cap")  # unreachable
         self.grow_budget(use_budget, int(-(counts.min()) - 1))
+        return True
 
-    def on_fused_overflow(self, counts: np.ndarray, use_budget: int) -> None:
-        """Fused-path retry: the one-dispatch kernel is capless (its mask
-        spans the whole slot table), so a negative count is ALWAYS budget
-        overflow carrying the total survivor count — the budget jumps
-        straight past it with no disambiguating bounds probe. A zeroed
-        budget hands the retry to the staged dense path."""
+    def on_capless_overflow(self, counts: np.ndarray,
+                            use_budget: int) -> None:
+        """Capless retry: the mask spans the whole slot table, so a negative
+        count is ALWAYS budget overflow carrying the total survivor count —
+        the budget jumps straight past it with no disambiguating bounds
+        probe."""
         self.escalations += 1
         if not use_budget:
             raise AssertionError(
-                "fused overflow without an active budget")  # unreachable
+                "capless overflow without an active budget")  # unreachable
         self.grow_budget(use_budget, int(-(counts.min()) - 1))
 
-    def on_sharded_overflow(self, counts: np.ndarray, use_budget: int,
-                            compaction: str) -> None:
+    def on_sharded_overflow(self, counts: np.ndarray,
+                            use_budget: int) -> None:
         """Sharded retry: the step encodes the exact LOCAL need — no global
         bounds probe, whose run is a useless overestimate of any one
-        shard's. The Pallas kernel scans the full local run (capless), so
-        with a budget active its overflow is ALWAYS the budget."""
+        shard's. Capless compaction with a budget active overflows ONLY on
+        the budget."""
         self.escalations += 1
         need = int(-(counts.min()) - 1)
-        if use_budget and compaction == "pallas":
+        if use_budget and self.capless:
             self.grow_budget(use_budget, need)
         elif need > self.cap:
             self.grow_cap(need)
@@ -266,6 +296,15 @@ class OverflowLadder:
                 "single-stage overflow with run <= cap")  # unreachable
         else:
             self.grow_budget(use_budget, need)
+
+
+def _capless(compaction: str) -> bool:
+    return compaction in ("pallas", "interpret")
+
+
+def _attempt_note(compaction: str, budget: int) -> str:
+    """What one refine attempt ran: its compaction, or the dense path."""
+    return f"compaction={compaction}" if budget else "dense"
 
 
 # ------------------------------------------------------------------- stages
@@ -322,7 +361,7 @@ class DeviceRefineStage(Stage):
     name = "refine"
     covers = ("probe", "compact", "refine")
     impl = "device"
-    dispatches = 3
+    dispatches = 1
 
     def run(self, ctx: ExecContext, st: StageStats) -> None:
         eng = _engine()
@@ -343,7 +382,8 @@ class DeviceRefineStage(Stage):
             ctx.frozen_delta = idx._freeze_delta() if patch else None
             ctx.live = idx._freeze_live(ctx.rel)
             ctx.epoch = idx._epoch
-            ladder = OverflowLadder(cfg, idx._cap)
+            comp = idx._compaction(ctx.base.name)
+            ladder = OverflowLadder(cfg, idx._cap, capless=_capless(comp))
         ctx.snap = snap
         pods, mb = payload
         q = len(batch.windows)
@@ -363,19 +403,20 @@ class DeviceRefineStage(Stage):
             ub = ladder.use_budget
             hits, counts = eng.batch_query(
                 snap, wj, pods, mb, relation=base,
-                cap=ladder.cap, exact_budget=ub,
-                compaction=idx._compaction(base, ub or None))
-            st.dispatches += 3 if ub else 2   # probe/compact/exact vs dense
+                cap=ladder.cap, exact_budget=ub, compaction=comp)
+            st.dispatches += 1
+            st.add_note(_attempt_note(comp, ub))
             counts = np.asarray(counts)
             if (counts >= 0).all():
                 with idx._lock:
                     # max-merge: a concurrent query may have grown it further
                     idx._cap = max(idx._cap, ladder.cap)
                 break
-            st.dispatches += 1                # disambiguating bounds probe
-            ladder.on_device_overflow(
-                counts, ub,
-                lambda: eng.batch_query_bounds(snap, wj, relation=base), q)
+            if ladder.on_device_overflow(
+                    counts, ub,
+                    lambda: eng.batch_query_bounds(snap, wj, relation=base),
+                    q):
+                st.dispatches += 1            # disambiguating bounds probe
         hits = np.asarray(hits)[:q]
         ctx.ids = [np.sort(row[row >= 0]).astype(np.int64) for row in hits]
         st.survivors = _total(ctx.ids)
@@ -384,17 +425,13 @@ class DeviceRefineStage(Stage):
 
 
 class FusedDeviceStage(Stage):
-    """ONE-dispatch probe+compact+refine: the whole staged pipeline of
-    :class:`DeviceRefineStage` executed by a single fused kernel launch
-    (``core.device.batch_query_fused``). Same freeze/retry/epilogue
-    contract; what changes is the vehicle — and ``dispatches`` telemetry
-    asserting the 3 -> 1 collapse.
-
-    The fused path is two-stage only and VMEM-bounded, so the stage
-    re-resolves ``SpatialIndex._fusion_mode`` every ladder step: a zeroed
-    budget (dense escalation) or an envelope the store outgrew falls back
-    to the staged ``batch_query`` for that attempt — correctness never
-    depends on fusion being available."""
+    """Whole-table probe+compact+refine (``core.device.batch_query_fused``,
+    ``EngineConfig.fusion="reference"``): one jitted XLA program that masks
+    EVERY slot against every window and compacts by cumsum + searchsorted —
+    capless, O(Q·N) memory, so for small stores and CPU timing. Same
+    freeze/retry/epilogue contract as :class:`DeviceRefineStage`; a dense
+    escalation (budget past ``max_cap``) runs the staged dense path for
+    that attempt."""
 
     name = "refine"
     covers = ("probe", "compact", "refine")
@@ -413,7 +450,7 @@ class FusedDeviceStage(Stage):
             ctx.frozen_delta = idx._freeze_delta() if patch else None
             ctx.live = idx._freeze_live(ctx.rel)
             ctx.epoch = idx._epoch
-            ladder = OverflowLadder(cfg, idx._cap)
+            ladder = OverflowLadder(cfg, idx._cap, capless=True)
         ctx.snap = snap
         pods, mb = payload
         q = len(batch.windows)
@@ -426,36 +463,32 @@ class FusedDeviceStage(Stage):
         base = ctx.base.name
         while True:
             ub = ladder.use_budget
-            mode = idx._fusion_mode(base, ub or None, snap=snap, pods=pods)
-            if mode is None:
-                # budget ladder left the fused envelope (dense escalation /
-                # budget past the VMEM bound): staged fallback this attempt
+            st.dispatches += 1
+            if idx._fusion_mode(base, ub) is None:
+                # dense escalation (survivors past max_cap): the staged
+                # dense path serves this attempt
                 hits, counts = eng.batch_query(
                     snap, wj, pods, mb, relation=base,
-                    cap=ladder.cap, exact_budget=ub,
-                    compaction=idx._compaction(base, ub or None))
-                st.dispatches += 3 if ub else 2
-                st.note = "fused envelope exceeded: staged fallback"
+                    cap=ladder.cap, exact_budget=0, compaction="scan")
+                st.add_note("dense")
                 counts = np.asarray(counts)
                 if (counts >= 0).all():
                     with idx._lock:
                         idx._cap = max(idx._cap, ladder.cap)
                     break
-                st.dispatches += 1            # disambiguating bounds probe
-                ladder.on_device_overflow(
-                    counts, ub,
-                    lambda: eng.batch_query_bounds(snap, wj, relation=base),
-                    q)
+                if ladder.on_device_overflow(
+                        counts, 0,
+                        lambda: eng.batch_query_bounds(snap, wj,
+                                                       relation=base), q):
+                    st.dispatches += 1        # disambiguating bounds probe
                 continue
             hits, counts = eng.batch_query_fused(
-                snap, wj, pods, relation=base, exact_budget=ub, mode=mode)
-            st.dispatches += 1
+                snap, wj, pods, relation=base, exact_budget=ub)
+            st.add_note("fusion=reference")
             counts = np.asarray(counts)
             if (counts >= 0).all():
-                with idx._lock:
-                    idx._cap = max(idx._cap, ladder.cap)
                 break
-            ladder.on_fused_overflow(counts, ub)   # capless: no bounds probe
+            ladder.on_capless_overflow(counts, ub)
         hits = np.asarray(hits)[:q]
         ctx.ids = [np.sort(row[row >= 0]).astype(np.int64) for row in hits]
         st.survivors = _total(ctx.ids)
@@ -473,7 +506,7 @@ class ShardedRefineStage(Stage):
     name = "refine"
     covers = ("probe", "compact", "refine")
     impl = "sharded"
-    dispatches = 3
+    dispatches = 1
 
     def run(self, ctx: ExecContext, st: StageStats) -> None:
         idx, batch = ctx.index, ctx.batch
@@ -495,21 +528,22 @@ class ShardedRefineStage(Stage):
                     [wins32, np.repeat(wins32[-1:], qpad, axis=0)])
             wj = jnp.asarray(wins32)
             snap_repl, table, _, maxw = idx._sharded_placement()
-            ladder = OverflowLadder(cfg, idx._cap)
             base = ctx.base.name
+            comp = idx._compaction(base)
+            if comp == "sort":  # legacy argsort baseline: 1-device only
+                comp = "scan"
+            ladder = OverflowLadder(cfg, idx._cap, capless=_capless(comp))
             while True:
                 ub = ladder.use_budget
-                comp = idx._compaction(base, ub or None)
-                if comp == "sort":  # legacy argsort baseline: 1-device only
-                    comp = "scan"
                 step = idx._sharded_step(base, ladder.cap, ub, comp, maxw)
                 hits, counts = step(snap_repl, wj, table)
-                st.dispatches += 3 if ub else 2
+                st.dispatches += 1
+                st.add_note(_attempt_note(comp, ub))
                 counts = np.asarray(counts)
                 if (counts >= 0).all():
                     idx._cap = max(idx._cap, ladder.cap)
                     break
-                ladder.on_sharded_overflow(counts, ub, comp)
+                ladder.on_sharded_overflow(counts, ub)
             hits = np.asarray(hits)[:q]              # (Q, shards, K)
             ctx.ids = [np.sort(row[row >= 0]).astype(np.int64)
                        for row in hits.reshape(q, -1)]
@@ -554,11 +588,13 @@ class DeltaPatchStage(Stage):
         if table is not None:
             wj = jnp.asarray(batch.windows.astype(np.float32))
             st.dispatches += 1           # device DeltaTable added-set check
+            st.note = "added set checked on device"
             ok = np.asarray(batch_check_added(
                 table, wj, base, snap.grid_x0, snap.grid_y0, snap.grid_cell))
             tbl_ids = np.asarray(table.ids, np.int64)
             added_hits = [np.sort(tbl_ids[row]) for row in ok]
         elif added.shape[0]:
+            st.note = "added set checked in a host loop"
             pred = get_relation(base).predicate
             added_hits = []
             for qi in range(len(ctx.ids)):
@@ -638,10 +674,10 @@ def _seed_radii(snap, wins, q, k, seed_mode, r_global, st,
     non-finite or non-positive (a point routed to an empty leaf, whose
     aggregate-MBR sentinel has no area) falls back to the global density
     radius — the seed is a performance prior, never allowed to poison the
-    probe relation. ``pow2`` snaps UP to powers of two — required where the
-    radius is a traced relation constant (the sharded ``dwithin:<r>``
-    classes); the device stage passes ``pow2=False`` because its radii ride
-    in the window coords and an up-snap only doubles the probe area."""
+    probe relation. ``pow2`` snaps UP to powers of two (the sharded stage's
+    doubling ladder); the device stage passes ``pow2=False`` because it
+    grows radii by its count-informed estimate and an up-snap only doubles
+    the probe area."""
     if seed_mode == "cdf":
         wq = wins.astype(np.float32)
         qb = 1 << max(q - 1, 0).bit_length()
@@ -663,16 +699,17 @@ def _knn_backstop(idx, cfg) -> tuple:
     top-k impl). ``knn_seed=None`` -> the CDF density seed (the planner only
     routes knn to a device backend when the learned model is published);
     ``knn_topk=None`` -> the Pallas partial-selection kernel on TPU, the
-    two-key ``lax.sort`` reference elsewhere."""
+    two-key ``lax.sort`` reference elsewhere; ``"interpret"`` runs the
+    kernel through interpret mode."""
     seed = cfg.knn_seed or "cdf"
     if seed not in ("cdf", "global"):
         raise ValueError(f"unknown knn_seed {cfg.knn_seed!r} "
                          "(use 'cdf' or 'global')")
     impl = cfg.knn_topk or (
         "pallas" if jax.default_backend() == "tpu" else "sort")
-    if impl not in ("sort", "pallas"):
+    if impl not in ("sort", "pallas", "interpret"):
         raise ValueError(f"unknown knn_topk {cfg.knn_topk!r} "
-                         "(use 'sort' or 'pallas')")
+                         "(use 'sort', 'pallas' or 'interpret')")
     return seed, impl
 
 
@@ -717,7 +754,7 @@ class KnnDeviceStage(Stage):
     name = "knn-rank"
     covers = ("probe", "compact", "refine", "knn-rank")
     impl = "device"
-    dispatches = 4
+    dispatches = 2
 
     def run(self, ctx: ExecContext, st: StageStats) -> None:
         eng = _engine()
@@ -736,7 +773,9 @@ class KnnDeviceStage(Stage):
             snap, payload = idx._replica_view(ctx.replica, snap, payload)
             ctx.frozen_delta = idx._freeze_delta() if patch else None
             ctx.epoch = idx._epoch
-            ladder = OverflowLadder(cfg, idx._cap, max_budget=cfg.max_cap)
+            comp = idx._compaction("intersects")
+            ladder = OverflowLadder(cfg, idx._cap, max_budget=cfg.max_cap,
+                                    capless=_capless(comp))
             n_live = idx.glin.num_records
             r_global = initial_knn_radius(idx.glin, k)
             seed_mode, impl = _knn_backstop(idx, cfg)
@@ -761,7 +800,7 @@ class KnnDeviceStage(Stage):
         radius = _seed_radii(snap, wins, q, k, seed_mode, r_global, st,
                              pow2=False)
         st.seed_radius = float(np.median(radius))
-        st.note = f"seed={seed_mode} topk={impl}"
+        st.add_note(f"seed={seed_mode} topk={impl}")
         # tier-1 budget: the CONFIGURED exact budget, pinned — the rank
         # stays narrow for the common case and only fat rows escalate
         # through `ladder` below. The tier-1 CAP tracks the ladder
@@ -798,12 +837,12 @@ class KnnDeviceStage(Stage):
             # its own small batch, so one straggler never drags the whole
             # batch onto a wide budget.
             c1 = ladder.cap
-            ub = b0 if 0 < b0 < c1 else 0
+            ub = b0 if b0 > 0 and (ladder.capless or b0 < c1) else 0
             hits, ch = eng.batch_query(
                 snap, jnp.asarray(sq), pods, mb, relation="intersects",
-                cap=c1, exact_budget=ub,
-                compaction=idx._compaction("intersects", ub or None))
-            st.dispatches += 3 if ub else 2
+                cap=c1, exact_budget=ub, compaction=comp)
+            st.dispatches += 1
+            st.add_note(_attempt_note(comp, ub))
             ch = np.asarray(ch)[: len(todo)]
             good = ch >= 0
             idk, dk, within = batch_knn_rank(
@@ -822,23 +861,21 @@ class KnnDeviceStage(Stage):
                 # for THIS rung's fat set up front — never the high-water
                 # mark of an earlier, fatter rung
                 need = int((-ch[fat] - 1).max())
-                t = 1 << max(need - 1, b0 - 1, 1).bit_length()
-                ladder.budget = (0 if t > ladder.max_budget
-                                 or t >= ladder.cap else t)
+                ladder.set_budget(1 << max(need - 1, b0 - 1, 1).bit_length())
                 fb = 1 << max(fat.size - 1, 0).bit_length()
                 fi = (np.concatenate([fat, np.repeat(fat[-1:],
                                                      fb - fat.size)])
                       if fb > fat.size else fat)
                 try:
-                    fhits = _knn_refine(idx, eng, snap, pods, mb,
-                                        jnp.asarray(sq[fi]), "intersects",
-                                        ladder, st)
+                    fhits = _knn_refine(eng, idx, snap, pods, mb,
+                                        jnp.asarray(sq[fi]), comp, ladder,
+                                        st)
                 except OverflowError:
                     # a straggler's radius outgrew max_cap: the host loop
                     # has no cap — finish the stragglers there instead of
                     # failing the whole batch
-                    st.note = ("straggler radius outgrew max_cap: "
-                               "host fallback")
+                    st.add_note("straggler radius outgrew max_cap: "
+                                "host fallback")
                     with idx._lock:
                         for i in todo[fat]:
                             hi, hd = _host_knn(idx.glin, pts[int(i)], k)
@@ -894,7 +931,7 @@ class KnnDeviceStage(Stage):
         st.seed_hits = int((probes == 1).sum())
 
 
-def _knn_refine(idx, eng, snap, pods, mb, wj, rel, ladder, st):
+def _knn_refine(eng, idx, snap, pods, mb, wj, comp, ladder, st):
     """One knn rung through the staged device refine — per-point inflated
     square windows over the plain ``intersects`` pipeline — under the
     shared overflow ladder (DeviceRefineStage's retry contract).
@@ -903,19 +940,21 @@ def _knn_refine(idx, eng, snap, pods, mb, wj, rel, ladder, st):
     while True:
         ub = ladder.use_budget
         hits, counts = eng.batch_query(
-            snap, wj, pods, mb, relation=rel,
-            cap=ladder.cap, exact_budget=ub,
-            compaction=idx._compaction(rel, ub or None))
-        st.dispatches += 3 if ub else 2
+            snap, wj, pods, mb, relation="intersects",
+            cap=ladder.cap, exact_budget=ub, compaction=comp)
+        st.dispatches += 1
+        st.add_note(_attempt_note(comp, ub))
         ch = np.asarray(counts)
         if (ch >= 0).all():
             with idx._lock:
                 idx._cap = max(idx._cap, ladder.cap)
             return hits
-        st.dispatches += 1                    # disambiguating bounds probe
-        ladder.on_device_overflow(
-            ch, ub, lambda: eng.batch_query_bounds(snap, wj, relation=rel),
-            wj.shape[0])
+        if ladder.on_device_overflow(
+                ch, ub,
+                lambda: eng.batch_query_bounds(snap, wj,
+                                               relation="intersects"),
+                wj.shape[0]):
+            st.dispatches += 1                # disambiguating bounds probe
 
 
 class KnnShardedStage(Stage):
@@ -932,13 +971,15 @@ class KnnShardedStage(Stage):
     Exactness contract: the sharded k-merge ranks SNAPSHOT records only, so
     a stale snapshot is always republished before probing — the fresh
     snapshot has no delta to merge, and results are exact at the published
-    epoch. Same per-point seeding / radius-class rung scheduling as
-    :class:`KnnDeviceStage`."""
+    epoch. Same per-point seeding as :class:`KnnDeviceStage`; each rung
+    probes every undone point in one dispatch at its own radius (a traced
+    input, so all radii share one compile), doubling the radius of every
+    point still short of k."""
 
     name = "knn-rank"
     covers = ("probe", "compact", "refine", "knn-rank")
     impl = "sharded"
-    dispatches = 4
+    dispatches = 1
 
     def run(self, ctx: ExecContext, st: StageStats) -> None:
         idx, batch = ctx.index, ctx.batch
@@ -958,7 +999,11 @@ class KnnShardedStage(Stage):
             n_live = idx.glin.num_records
             r_global = initial_knn_radius(idx.glin, k)
             seed_mode, _ = _knn_backstop(idx, cfg)
-            ladder = OverflowLadder(cfg, idx._cap, max_budget=cfg.max_cap)
+            comp = idx._compaction("intersects")
+            if comp == "sort":   # legacy argsort baseline: 1-device only
+                comp = "scan"
+            ladder = OverflowLadder(cfg, idx._cap, max_budget=cfg.max_cap,
+                                    capless=_capless(comp))
             m = cfg.mesh.shape["model"]
             ctx.ids = [np.empty(0, np.int64) for _ in range(q)]
             ctx.distances = [np.empty(0, np.float64) for _ in range(q)]
@@ -967,7 +1012,7 @@ class KnnShardedStage(Stage):
                 return
             radius = _seed_radii(snap, wins, q, k, seed_mode, r_global, st)
             st.seed_radius = float(np.median(radius))
-            st.note = f"seed={seed_mode}"
+            st.add_note(f"seed={seed_mode}")
             done = np.zeros(q, bool)
             probes = np.zeros(q, np.int32)
             out_ids: List[Optional[np.ndarray]] = list(ctx.ids)
@@ -976,42 +1021,44 @@ class KnnShardedStage(Stage):
                 todo = np.nonzero(~done)[0]
                 if todo.size == 0:
                     break
-                for r in [float(v) for v in np.unique(radius[todo])]:
-                    sel = todo[radius[todo] == r]
-                    sub = wins[sel].astype(np.float32)
-                    # pow2 bucket rounded up to a model-axis multiple
-                    # (shard_map divides Q evenly)
-                    b = 1 << max(len(sel) - 1, 0).bit_length()
-                    b += (-b) % m
-                    if b > len(sel):
-                        sub = np.concatenate(
-                            [sub, np.repeat(sub[-1:], b - len(sel), 0)])
-                    wj = jnp.asarray(sub)
-                    relname = f"dwithin:{r:.17g}"
-                    probes[sel] += 1
-                    try:
-                        idk, dk, within = self._rank(idx, snap_repl, table,
-                                                     wj, relname, k, maxw,
-                                                     ladder, st, b, shards)
-                    except OverflowError:
-                        st.note = ("straggler radius outgrew max_cap: "
-                                   "host fallback")
-                        for i in sel:
-                            hi, hd = _host_knn(idx.glin, pts[int(i)], k)
-                            out_ids[int(i)] = np.asarray(hi, np.int64)
-                            out_d[int(i)] = np.asarray(hd)
-                        done[sel] = True
-                        continue
-                    idk = idk[: len(sel)]
-                    dk = dk[: len(sel)]
-                    within = within[: len(sel)]
-                    settle = (within >= k) | (within >= n_live)
-                    for j in np.nonzero(settle)[0]:
-                        i = int(sel[j])
-                        keep = idk[j] >= 0
-                        out_ids[i] = idk[j][keep].astype(np.int64)
-                        out_d[i] = dk[j][keep].astype(np.float64)
-                    done[sel[settle]] = True
+                # every undone point in ONE dispatch, each at its own
+                # radius, padded to the WHOLE batch's pow2 bucket (a
+                # model-axis multiple: shard_map divides Q evenly) so later
+                # rungs reuse the first rung's compiled step
+                sub = wins[todo].astype(np.float32)
+                rr = radius[todo].astype(np.float32)
+                b = 1 << max(q - 1, 0).bit_length()
+                b += (-b) % m
+                if b > len(todo):
+                    sub = np.concatenate(
+                        [sub, np.repeat(sub[-1:], b - len(todo), 0)])
+                    rr = np.concatenate(
+                        [rr, np.repeat(rr[-1:], b - len(todo))])
+                probes[todo] += 1
+                try:
+                    idk, dk, within = self._rank(
+                        idx, snap_repl, table, jnp.asarray(sub),
+                        jnp.asarray(rr), k, maxw, comp, ladder, st, b,
+                        shards)
+                except OverflowError:
+                    st.add_note("straggler radius outgrew max_cap: "
+                                "host fallback")
+                    for i in todo:
+                        hi, hd = _host_knn(idx.glin, pts[int(i)], k)
+                        out_ids[int(i)] = np.asarray(hi, np.int64)
+                        out_d[int(i)] = np.asarray(hd)
+                    done[todo] = True
+                    continue
+                idk = idk[: len(todo)]
+                dk = dk[: len(todo)]
+                within = within[: len(todo)]
+                settle = (within >= k) | (within >= n_live)
+                for j in np.nonzero(settle)[0]:
+                    i = int(todo[j])
+                    keep = idk[j] >= 0
+                    out_ids[i] = idk[j][keep].astype(np.int64)
+                    out_d[i] = dk[j][keep].astype(np.float64)
+                done[todo[settle]] = True
                 radius[~done] *= 2.0
             else:
                 raise RuntimeError("knn did not converge")
@@ -1026,19 +1073,16 @@ class KnnShardedStage(Stage):
         st.seed_hits = int((probes == 1).sum())
 
     @staticmethod
-    def _rank(idx, snap_repl, table, wj, relname, k, maxw, ladder, st,
+    def _rank(idx, snap_repl, table, wj, rj, k, maxw, comp, ladder, st,
               qpad, shards):
         """One sharded probe+rank+k-merge dispatch under the ladder. Caller
         holds the facade lock (ShardedRefineStage's contract)."""
         while True:
             ub = ladder.use_budget
-            comp = idx._compaction(relname, ub or None)
-            if comp == "sort":   # legacy argsort baseline: 1-device only
-                comp = "scan"
-            step = idx._sharded_knn_step(relname, k, ladder.cap, ub, comp,
-                                         maxw)
-            idk, dk, counts = step(snap_repl, wj, table)
-            st.dispatches += 4 if ub else 3
+            step = idx._sharded_knn_step(k, ladder.cap, ub, comp, maxw)
+            idk, dk, counts = step(snap_repl, wj, rj, table)
+            st.dispatches += 1
+            st.add_note(_attempt_note(comp, ub))
             # all-gathered (shards, Q, k) blocks — k f32 distances + k i32
             # ids per shard — plus the (Q, shards) i32 counts
             st.merge_bytes += qpad * shards * (k * 8 + 4)
@@ -1047,7 +1091,7 @@ class KnnShardedStage(Stage):
                 idx._cap = max(idx._cap, ladder.cap)
                 return (np.asarray(idk), np.asarray(dk),
                         counts.sum(axis=1))
-            ladder.on_sharded_overflow(counts, ub, comp)
+            ladder.on_sharded_overflow(counts, ub)
 
 
 # ------------------------------------------------------------- execution plan

@@ -1,34 +1,33 @@
-"""Pallas TPU kernels: tiled GLIN refinement (mask / count / fused compact).
+"""Pallas TPU kernels: tiled GLIN refinement (mask / count / compact) and
+the kNN top-k selection.
 
 The paper's profile (§IX-D) shows refinement dominates query time. These
 kernels evaluate the (query-window × record) MBR tests in VMEM tiles, fused
 with the Z-interval slot test (``start <= slot < end``), so a (BQ × BN) tile
 of candidates is disposed of per grid step without materializing gathers in
-HBM.
+HBM. MBR tables stream as lane-dense (4, BN) tiles.
 
-Four entry points (all pad internally — arbitrary Q and N just work):
+Entry points (all pad internally — arbitrary Q and N just work):
 
 * ``refine_mask_pallas``    — full (Q, N) int8 mask.
 * ``refine_count_pallas``   — (Q,) int32 match counts via grid-axis
   accumulation (selectivity estimation at device speed).
-* ``refine_compact_pallas`` — the two-dispatch refinement front-end: fused
-  interval + leaf-MBR + record-MBR mask with in-VMEM prefix-sum compaction.
-  Emits the per-query compacted candidate slots ``(Q, budget)`` plus
-  survivor counts, replacing both the dense ``(Q, cap)`` mask
-  materialization and the ``O(Q·cap·log cap)`` argsort compaction in
-  ``core.device.batch_query``: only ``Q·budget`` slot ids ever reach HBM,
-  and the expensive exact-shape vertex gathers downstream shrink from
-  ``(Q·cap·V)`` to ``(Q·budget·V)``.
-* ``refine_fused_pallas``   — the ONE-dispatch query (ROADMAP one-kernel
-  queries): the learned-index probe (piecewise suffix-min augmentation,
-  model traversal, bounded binary search — all model tables VMEM-resident),
-  the compact stage above, AND the exact rect-vs-geometry tests over the
-  ``VertexPods`` pool, in a single kernel. The ``(Q, 2)`` probe bounds and
-  ``(Q, budget)`` survivor slots never round-trip HBM; only the final
-  record-id hits and counts leave the chip. Bit-identical to composing
-  ``batch_query_bounds`` + the compact stage + the exact stage
-  (``core.device.batch_query_fused(mode="reference")`` is that composition
-  in one jit).
+* ``refine_compact_pallas`` — the refinement front-end: fused interval +
+  leaf-MBR + record-MBR mask with in-VMEM prefix-sum compaction. Emits the
+  per-query compacted candidate slots ``(Q, budget)`` plus survivor counts,
+  replacing both the dense ``(Q, cap)`` mask materialization and the
+  ``O(Q·cap·log cap)`` argsort compaction in ``core.device.batch_query``:
+  only ``Q·budget`` slot ids ever reach HBM, and the expensive exact-shape
+  vertex gathers downstream shrink from ``(Q·cap·V)`` to ``(Q·budget·V)``.
+  The one-dispatch query (``core.device.batch_query_fused``) runs this
+  kernel between the probe and the exact stage inside one jit: the probe's
+  binary searches and the exact stage's pod gathers index tables with
+  per-lane dynamic indices, which Mosaic cannot lower inside a kernel.
+* ``knn_topk_pallas``       — k-round partial selection for the kNN rank.
+
+The kernels use no scatter, no gather and no cumsum (Mosaic lowers none of
+them): prefix sums are one MXU product with a triangular ones matrix, and
+compaction is a one-hot reduction.
 
 ``refine_cost`` is the analytic bytes/flops model of each kernel (used both
 as the ``pl.CostEstimate`` handed to the compiler and by
@@ -45,36 +44,45 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BQ = 8
 DEFAULT_BN = 512
-COMPACT_BN = 256      # smaller record tiles: the one-hot scatter tensor is
-                      # (BQ, BN, budget) in VMEM
-MAX_COMPACT_BUDGET = 1024   # (bq=8, bn=256, budget=1024) int32 = 8 MB — the
-                            # scatter tensor must fit ~16 MB TPU VMEM next to
-                            # the streamed tiles; larger budgets must take
-                            # the jnp "scan" path (no VMEM constraint)
+COMPACT_BN = 256      # smaller record tiles: the one-hot scatter block is
+                      # (budget, BN) per query row in VMEM
+COMPACT_CHUNK = 1024  # output columns one pass of the compact kernel
+                      # fills: its (chunk, BN) int32 one-hot block (1 MiB)
+                      # must fit the scoped VMEM next to the streamed tiles.
+                      # Larger budgets take one pass per chunk
 _NEVER = 2e30         # padding MBR coordinate: intersects nothing
-_LO_LIMB_F = float(1 << 30)   # fp32 weight of the hi limb (zorder.LO_LIMB_SIZE)
-_LIMB_MAX = (1 << 30) - 1     # largest valid limb value: key padding that
-                              # preserves sorted order past the true table
-_INF_HI = 2 ** 30             # hi-limb +inf sentinel (zorder._INF_HI)
-FUSED_VMEM_LIMIT = 12 << 20   # budget for the fused kernel's VMEM residency
-                              # (model tables + pods + scatter block); past it
-                              # the engine falls back to the staged pipeline
+
+
+def _mbr_rows(ref):
+    """(4, BN) lane-dense MBR tile -> its four (1, BN) coordinate rows."""
+    m = ref[...]
+    return m[0:1, :], m[1:2, :], m[2:3, :], m[3:4, :]
+
+
+def _win_cols(ref):
+    """(BQ, 4) window tile -> its four (BQ, 1) coordinate columns."""
+    w = ref[...]
+    return w[:, 0:1], w[:, 1:2], w[:, 2:3], w[:, 3:4]
+
+
+def _intersects(w, m):
+    """(BQ,1) window columns x (1,BN) MBR rows -> (BQ,BN) bool overlap."""
+    return ((w[0] <= m[2]) & (m[0] <= w[2])
+            & (w[1] <= m[3]) & (m[1] <= w[3]))
+
+
+def _in_run(bounds_ref, nb, bn, shape):
+    """Slot-run test: tile column c is slot ``nb*bn + c``; the run is the
+    row's ``[start, end)`` from the (BQ, 2) bounds block."""
+    b = bounds_ref[...]
+    slot = nb * bn + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (slot >= b[:, 0:1]) & (slot < b[:, 1:2])
 
 
 def _tile_mask(win_ref, mbr_ref, bounds_ref, nb, bn):
-    """(BQ,4) windows x (BN,4) record MBRs -> (BQ,BN) bool."""
-    w = win_ref[...]          # (BQ, 4)
-    r = mbr_ref[...]          # (BN, 4)
-    b = bounds_ref[...]       # (BQ, 2) int32 [start, end)
-    inter = (
-        (w[:, None, 0] <= r[None, :, 2])
-        & (r[None, :, 0] <= w[:, None, 2])
-        & (w[:, None, 1] <= r[None, :, 3])
-        & (r[None, :, 1] <= w[:, None, 3])
-    )
-    slot = nb * bn + jax.lax.broadcasted_iota(jnp.int32, inter.shape, 1)
-    in_run = (slot >= b[:, 0:1]) & (slot < b[:, 1:2])
-    return inter & in_run
+    """(BQ,4) windows x (4,BN) record MBRs -> (BQ,BN) bool."""
+    inter = _intersects(_win_cols(win_ref), _mbr_rows(mbr_ref))
+    return inter & _in_run(bounds_ref, nb, bn, inter.shape)
 
 
 def _mask_kernel(win_ref, bounds_ref, mbr_ref, out_ref, *, bn):
@@ -85,7 +93,7 @@ def _mask_kernel(win_ref, bounds_ref, mbr_ref, out_ref, *, bn):
 def _count_kernel(win_ref, bounds_ref, mbr_ref, out_ref, *, bn):
     nb = pl.program_id(1)
     mask = _tile_mask(win_ref, mbr_ref, bounds_ref, nb, bn)
-    partial_counts = mask.sum(axis=1).astype(jnp.int32)
+    partial_counts = mask.sum(axis=1, keepdims=True).astype(jnp.int32)
 
     @pl.when(nb == 0)
     def _init():
@@ -102,47 +110,49 @@ def _compact_tile_mask(win_ref, lmbr_ref, rmbr_ref, bounds_ref, nb, bn,
     relations); ``prefilter`` selects the record-MBR test shape:
     "intersects" (record MBR meets the probe window) or "contains" (record
     MBR covers the window — the ``within`` prefilter)."""
-    w = win_ref[...]          # (BQ, 4) probe windows
-    lm = lmbr_ref[...]        # (BN, 4) per-slot leaf MBRs
-    rm = rmbr_ref[...]        # (BN, 4) per-slot record MBRs
-    b = bounds_ref[...]       # (BQ, 2) int32 [start, end)
-    leaf_ok = (
-        (w[:, None, 0] <= lm[None, :, 2])
-        & (lm[None, :, 0] <= w[:, None, 2])
-        & (w[:, None, 1] <= lm[None, :, 3])
-        & (lm[None, :, 1] <= w[:, None, 3])
-    )
+    w = _win_cols(win_ref)
+    rm = _mbr_rows(rmbr_ref)
+    leaf_ok = _intersects(w, _mbr_rows(lmbr_ref))
     if prefilter == "contains":
-        rec_ok = (
-            (rm[None, :, 0] <= w[:, None, 0])
-            & (rm[None, :, 1] <= w[:, None, 1])
-            & (w[:, None, 2] <= rm[None, :, 2])
-            & (w[:, None, 3] <= rm[None, :, 3])
-        )
+        rec_ok = ((rm[0] <= w[0]) & (rm[1] <= w[1])
+                  & (w[2] <= rm[2]) & (w[3] <= rm[3]))
     else:
-        rec_ok = (
-            (w[:, None, 0] <= rm[None, :, 2])
-            & (rm[None, :, 0] <= w[:, None, 2])
-            & (w[:, None, 1] <= rm[None, :, 3])
-            & (rm[None, :, 1] <= w[:, None, 3])
-        )
-    slot = nb * bn + jax.lax.broadcasted_iota(jnp.int32, leaf_ok.shape, 1)
-    in_run = (slot >= b[:, 0:1]) & (slot < b[:, 1:2])
-    return leaf_ok & rec_ok & in_run
+        rec_ok = _intersects(w, rm)
+    return leaf_ok & rec_ok & _in_run(bounds_ref, nb, bn, leaf_ok.shape)
+
+
+def _exclusive_prefix(m32, bn):
+    """Exclusive prefix sum along the lanes of a (BQ, BN) 0/1 tile, as one
+    MXU product with the upper-triangular ones matrix (Mosaic has no
+    cumsum). Exact: the 0/1 operands are exact in bf16, and the partial
+    sums (<= BN) are exact in the f32 accumulator."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1)
+    tri = (row <= col).astype(jnp.bfloat16)
+    incl = jnp.dot(m32.astype(jnp.bfloat16), tri,
+                   preferred_element_type=jnp.float32)
+    return incl.astype(jnp.int32) - m32
 
 
 def _compact_kernel(win_ref, bounds_ref, lmbr_ref, rmbr_ref,
-                    slots_ref, count_ref, *, bn, budget, prefilter):
-    """Grid step (i, j): mask the (BQ, BN) tile, then prefix-sum compact the
-    survivors into the revisited (BQ, budget) output block.
+                    slots_ref, count_ref, *, bn, chunk, prefilter):
+    """Grid step (i, c, j): mask the (BQ, BN) record tile j, then prefix-sum
+    compact the survivors whose output column falls in chunk c —
+    ``[c * chunk, (c + 1) * chunk)`` — into the revisited (BQ, chunk)
+    output block.
 
-    ``count_ref`` carries the running per-query survivor count across the
-    record axis; a survivor's output column is that running count plus its
-    exclusive within-tile prefix sum. The scatter itself is a one-hot
-    reduction over the tile (TPU vector units have no scatter): survivors
-    past ``budget`` only advance the count — overflow is ``count > budget``,
-    signalled to the caller, never silent truncation."""
-    nb = pl.program_id(1)
+    ``count_ref`` ((BQ, 1): a rank-1 (BQ,) block is neither the whole array
+    nor a multiple of 128 lanes, which Mosaic refuses) carries the running
+    per-query survivor count across the record axis (restarted for every
+    chunk, so the last chunk leaves the total); a survivor's output column
+    is that running count plus its exclusive within-tile prefix sum. The
+    scatter itself is a one-hot reduction (TPU vector units have no
+    scatter), one query row at a time over a (chunk, BN) block so every
+    operand stays 2D, and runs only on tiles that put a survivor into this
+    chunk. Survivors past the budget only advance the count — overflow is
+    ``count > budget``, signalled to the caller, never silent truncation."""
+    c = pl.program_id(1)
+    nb = pl.program_id(2)
 
     @pl.when(nb == 0)
     def _init():
@@ -152,195 +162,26 @@ def _compact_kernel(win_ref, bounds_ref, lmbr_ref, rmbr_ref,
     mask = _compact_tile_mask(win_ref, lmbr_ref, rmbr_ref, bounds_ref, nb, bn,
                               prefilter)
     m32 = mask.astype(jnp.int32)
-    base = count_ref[...]                            # (BQ,)
-    excl = jnp.cumsum(m32, axis=1) - m32             # exclusive prefix
-    pos = base[:, None] + excl                       # output column per slot
-    sel = mask & (pos < budget)
-    slot = nb * bn + jax.lax.broadcasted_iota(jnp.int32, mask.shape, 1)
-    # one-hot scatter: out[q, k] = slot of the survivor whose pos == k
-    cols = jax.lax.broadcasted_iota(jnp.int32, (mask.shape[0], bn, budget), 2)
-    hot = (pos[:, :, None] == cols) & sel[:, :, None]
-    written = (hot * (slot + 1)[:, :, None]).sum(axis=1)   # 0 where no write
-    slots_ref[...] = jnp.where(written > 0, written - 1, slots_ref[...])
-    count_ref[...] = base + m32.sum(axis=1)
+    base = count_ref[...]                            # (BQ, 1)
+    tile = m32.sum(axis=1, keepdims=True)
+    count_ref[...] = base + tile
+    lo = c * chunk
+    lands = (tile > 0) & (base < lo + chunk) & (base + tile > lo)
 
-
-def _z_less(a_hi, a_lo, b_hi, b_lo):
-    """a < b on (hi, lo) Z-address limb pairs (zorder.z_less_hilo)."""
-    return (a_hi < b_hi) | ((a_hi == b_hi) & (a_lo < b_lo))
-
-
-def _fused_probe(qk, keys_hi, keys_lo, li, lf, ni, nf, codes, pw, *,
-                 augment, search_steps, depth):
-    """In-kernel port of ``core.device`` ``_augment`` + ``batch_probe`` over
-    the VMEM-resident packed model tables — same fp32 op order, so the probe
-    bounds are bit-identical to ``batch_query_bounds``.
-
-    ``qk`` is (BQ, 4) int32 ``[zmin_hi, zmin_lo, ub_hi, ub_lo]`` PRE-
-    augmentation query keys (the O(Q) window quantization stays outside the
-    kernel); table layouts are documented on ``refine_fused_pallas``.
-    Returns the ``[start, end)`` slot run per query row."""
-    zmin_hi, zmin_lo = qk[:, 0], qk[:, 1]
-    ub_hi, ub_lo = qk[:, 2], qk[:, 3]
-
-    if augment:
-        # suffix-min piecewise augmentation: first piece with zmax >= zmin,
-        # then take its suffix-min Zmin when it precedes the query key
-        p = pw.shape[0]
-        steps = max(1, math.ceil(math.log2(p + 1)))
-        alo = jnp.zeros_like(zmin_hi)
-        ahi = jnp.full_like(zmin_hi, p)
-
-        def astep(_, st):
-            lo_i, hi_i = st
-            mid = (lo_i + hi_i) >> 1
-            less = _z_less(pw[mid, 0], pw[mid, 1], zmin_hi, zmin_lo)
-            return jnp.where(less, mid + 1, lo_i), jnp.where(less, hi_i, mid)
-
-        alo, _ = jax.lax.fori_loop(0, steps, astep, (alo, ahi))
-        in_range = alo < p
-        idx = jnp.minimum(alo, p - 1)
-        m_hi = jnp.where(in_range, pw[idx, 2], _INF_HI)
-        m_lo = jnp.where(in_range, pw[idx, 3], 0)
-        take = _z_less(m_hi, m_lo, zmin_hi, zmin_lo)
-        zmin_hi = jnp.where(take, m_hi, zmin_hi)
-        zmin_lo = jnp.where(take, m_lo, zmin_lo)
-
-    num_leaves = li.shape[0] - 1
-
-    def find_leaf(q_hi, q_lo):
-        def body(_, state):
-            node, leaf, done = state
-            dh = (q_hi - ni[node, 0]).astype(jnp.float32)
-            dl = (q_lo - ni[node, 1]).astype(jnp.float32)
-            key_f = dh * jnp.float32(_LO_LIMB_F) + dl
-            cell_f = jnp.clip(jnp.floor(key_f * nf[node, 0]), 0.0,
-                              (ni[node, 2] - 1).astype(jnp.float32))
-            cell = cell_f.astype(jnp.int32)
-            code = codes[ni[node, 3] + cell, 0]
-            is_leaf = code < 0
-            new_leaf = jnp.where(is_leaf & ~done, -code - 1, leaf)
-            new_node = jnp.where(is_leaf | done, node, code)
-            return new_node, new_leaf, done | is_leaf
-
-        node0 = jnp.zeros_like(q_hi)
-        leaf0 = jnp.zeros_like(q_hi)
-        done0 = jnp.zeros(q_hi.shape, bool)
-        _, leaf, _ = jax.lax.fori_loop(0, depth, body, (node0, leaf0, done0))
-        # fp32 routing fix-up against exact integer leaf-domain boundaries
-        for _ in range(2):
-            too_low = _z_less(q_hi, q_lo, li[leaf, 1], li[leaf, 2])
-            leaf = jnp.maximum(leaf - too_low.astype(jnp.int32), 0)
-            too_high = ~_z_less(q_hi, q_lo, li[leaf + 1, 1], li[leaf + 1, 2])
-            leaf = jnp.minimum(leaf + too_high.astype(jnp.int32),
-                               num_leaves - 1)
-        return leaf
-
-    def probe(q_hi, q_lo):
-        leaf = find_leaf(q_hi, q_lo)
-        start = li[leaf, 0]
-        end = li[leaf + 1, 0]
-        size = end - start
-        key_f = ((q_hi - li[leaf, 3]).astype(jnp.float32)
-                 * jnp.float32(_LO_LIMB_F)
-                 + (q_lo - li[leaf, 4]).astype(jnp.float32))
-        pred = jnp.rint(lf[leaf, 0] * key_f + lf[leaf, 1]).astype(jnp.int32)
-        pred = jnp.clip(pred, 0, jnp.maximum(size - 1, 0))
-        err = (1 << search_steps) // 2 + 2
-        lo = jnp.maximum(pred - err, 0) + start
-        hi = jnp.minimum(pred + err, size) + start
-
-        def bstep(_, st):
-            lo_i, hi_i = st
-            live = lo_i < hi_i  # converged lanes must not move
-            mid = (lo_i + hi_i) >> 1
-            less = _z_less(keys_hi[mid], keys_lo[mid], q_hi, q_lo) & live
-            return (jnp.where(less, mid + 1, lo_i),
-                    jnp.where(less | ~live, hi_i, mid))
-
-        lo, _ = jax.lax.fori_loop(0, search_steps + 2, bstep, (lo, hi))
-        return lo
-
-    return probe(zmin_hi, zmin_lo), probe(ub_hi, ub_lo)
-
-
-def _fused_kernel(win_ref, pwin_ref, qk_ref, keys_ref, recs_ref, leaf_i_ref,
-                  leaf_f_ref, node_i_ref, node_f_ref, codes_ref, pw_ref,
-                  pod_ref, pool_ref, lmbr_ref, rmbr_ref,
-                  slots_ref, count_ref, bounds_ref, *,
-                  bn, budget, lanes, prefilter, predicate, augment,
-                  search_steps, depth, num_buckets):
-    """Grid step (i, j) of the one-dispatch query.
-
-    j == 0: probe the learned index for the (BQ,) query tile and park the
-    slot runs in the revisited ``bounds_ref`` output block (outputs double
-    as cross-step state, like ``_compact_kernel``'s count). Every j: mask +
-    prefix-sum compact the (BQ, BN) slot tile exactly as ``_compact_kernel``
-    — except survivors index with the TRUE budget, not the lane-aligned
-    block width, so the in-kernel exact stage sees exactly the (Q, budget)
-    survivor prefix the two-dispatch reference sees. j == last: gather the
-    survivors' records and vertex pods (at the widest pow2 bucket among the
-    tile's survivors) and overwrite the slot block with final record-id hits
-    and the count block with exact-hit counts — or ``-(survivors) - 1`` on
-    budget overflow (the fused path is capless, so overflow is ALWAYS the
-    budget)."""
-    nb = pl.program_id(1)
-
-    @pl.when(nb == 0)
-    def _probe():
-        start, end = _fused_probe(
-            qk_ref[...], keys_ref[:, 0], keys_ref[:, 1], leaf_i_ref[...],
-            leaf_f_ref[...], node_i_ref[...], node_f_ref[...],
-            codes_ref[...], pw_ref[...], augment=augment,
-            search_steps=search_steps, depth=depth)
-        bounds_ref[...] = jnp.stack([start, end], axis=1)
-        slots_ref[...] = jnp.full_like(slots_ref, -1)
-        count_ref[...] = jnp.zeros_like(count_ref)
-
-    mask = _compact_tile_mask(pwin_ref, lmbr_ref, rmbr_ref, bounds_ref, nb,
-                              bn, prefilter)
-    m32 = mask.astype(jnp.int32)
-    base = count_ref[...]
-    excl = jnp.cumsum(m32, axis=1) - m32
-    pos = base[:, None] + excl
-    sel = mask & (pos < budget)
-    slot = nb * bn + jax.lax.broadcasted_iota(jnp.int32, mask.shape, 1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (mask.shape[0], bn, lanes), 2)
-    hot = (pos[:, :, None] == cols) & sel[:, :, None]
-    written = (hot * (slot + 1)[:, :, None]).sum(axis=1)
-    slots_ref[...] = jnp.where(written > 0, written - 1, slots_ref[...])
-    count_ref[...] = base + m32.sum(axis=1)
-
-    @pl.when(nb == pl.num_programs(1) - 1)
-    def _exact():
-        slots = slots_ref[...]
-        total = count_ref[...]
-        taken = slots >= 0
-        slotc = jnp.maximum(slots, 0)
-        rec = jnp.where(taken, recs_ref[:, 0][slotc], 0)
-        pod = pod_ref[...]
-        off = pod[:, 0][rec]
-        nv = pod[:, 1][rec]
-        kd = pod[:, 2][rec]
-        b = jnp.max(jnp.where(taken, pod[:, 3][rec], 0))
-        pool = pool_ref[...]
-        w = win_ref[...]
-
-        def branch(width):
-            def run(off, nv, kd):
-                lane = jnp.minimum(
-                    jax.lax.broadcasted_iota(jnp.int32, off.shape + (width,),
-                                             off.ndim),
-                    nv[..., None] - 1)
-                idx = jnp.clip(off[..., None] + lane, 0, pool.shape[0] - 1)
-                return jax.vmap(predicate)(w, pool[idx], nv, kd)
-            return run
-
-        fmask = taken & jax.lax.switch(
-            b, [branch(1 << i) for i in range(num_buckets)], off, nv, kd)
-        slots_ref[...] = jnp.where(fmask, rec, -1)
-        count_ref[...] = jnp.where(total > budget, -total - 1,
-                                   fmask.sum(axis=1).astype(jnp.int32))
+    @pl.when(jnp.max(lands.astype(jnp.int32)) > 0)
+    def _scatter():
+        pos = base + _exclusive_prefix(m32, bn) - lo  # column in this chunk
+        sel = mask & (pos >= 0) & (pos < chunk)
+        lane1 = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1) + 1
+        out_col = jax.lax.broadcasted_iota(jnp.int32, (chunk, bn), 0)
+        # written[:, r] = 1 + the tile lane that lands in each output
+        # column of query row r (0 where none does)
+        written = jnp.concatenate(
+            [jnp.where((out_col == pos[r:r + 1]) & sel[r:r + 1], lane1, 0)
+             .sum(axis=1, keepdims=True) for r in range(mask.shape[0])],
+            axis=1).T                                # (BQ, chunk)
+        slots_ref[...] = jnp.where(written > 0, nb * bn + written - 1,
+                                   slots_ref[...])
 
 
 def _grids(q, n, bq, bn):
@@ -349,9 +190,11 @@ def _grids(q, n, bq, bn):
 
 
 def _pad_inputs(windows, bounds, bq, bn, *mbr_tables):
-    """Pad Q to a multiple of bq and N to a multiple of bn. Padded MBR rows
-    sit at ``_NEVER`` (intersect nothing, contain nothing); padded query rows
-    get empty [0, 0) runs. Callers slice outputs back to (q, n)."""
+    """Pad Q to a multiple of bq and N to a multiple of bn, and lay the
+    (N, 4) MBR tables out lane-dense as (4, N) (a (BN, 4) block would fill
+    4 of 128 lanes). Padded MBR columns sit at ``_NEVER`` (intersect
+    nothing, contain nothing); padded query rows get empty [0, 0) runs.
+    Callers slice outputs back to (q, n)."""
     q, n = windows.shape[0], mbr_tables[0].shape[0]
     qp, np_ = (-q) % bq, (-n) % bn
     if qp:
@@ -361,7 +204,7 @@ def _pad_inputs(windows, bounds, bq, bn, *mbr_tables):
     for m in mbr_tables:
         if np_:
             m = jnp.pad(m, ((0, np_), (0, 0)), constant_values=_NEVER)
-        padded.append(m)
+        padded.append(m.T)
     return windows, bounds, padded
 
 
@@ -371,14 +214,14 @@ def refine_mask_pallas(windows: jax.Array, bounds: jax.Array, mbrs: jax.Array,
     """windows (Q,4) f32, bounds (Q,2) i32, mbrs (N,4) f32 -> (Q,N) int8."""
     q, n = windows.shape[0], mbrs.shape[0]
     windows, bounds, (mbrs,) = _pad_inputs(windows, bounds, bq, bn, mbrs)
-    qp, np_ = windows.shape[0], mbrs.shape[0]
+    qp, np_ = windows.shape[0], mbrs.shape[1]
     out = pl.pallas_call(
         partial(_mask_kernel, bn=bn),
         grid=_grids(qp, np_, bq, bn),
         in_specs=[
             pl.BlockSpec((bq, 4), lambda i, j: (i, 0)),
             pl.BlockSpec((bq, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 4), lambda i, j: (j, 0)),
+            pl.BlockSpec((4, bn), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp, np_), jnp.int8),
@@ -395,21 +238,21 @@ def refine_count_pallas(windows: jax.Array, bounds: jax.Array, mbrs: jax.Array,
     accumulated in the revisited output block)."""
     q = windows.shape[0]
     windows, bounds, (mbrs,) = _pad_inputs(windows, bounds, bq, bn, mbrs)
-    qp, np_ = windows.shape[0], mbrs.shape[0]
+    qp, np_ = windows.shape[0], mbrs.shape[1]
     out = pl.pallas_call(
         partial(_count_kernel, bn=bn),
         grid=_grids(qp, np_, bq, bn),
         in_specs=[
             pl.BlockSpec((bq, 4), lambda i, j: (i, 0)),
             pl.BlockSpec((bq, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 4), lambda i, j: (j, 0)),
+            pl.BlockSpec((4, bn), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bq,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((qp,), jnp.int32),
+        out_specs=pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((qp, 1), jnp.int32),
         cost_estimate=_cost_estimate("count", qp, np_),
         interpret=interpret,
     )(windows, bounds, mbrs)
-    return out[:q]
+    return out[:q, 0]
 
 
 def refine_compact_pallas(windows: jax.Array, bounds: jax.Array,
@@ -427,141 +270,38 @@ def refine_compact_pallas(windows: jax.Array, bounds: jax.Array,
     """
     if prefilter not in ("intersects", "contains"):
         raise ValueError(f"unsupported prefilter {prefilter!r}")
-    if budget > MAX_COMPACT_BUDGET:
-        raise ValueError(
-            f"budget {budget} exceeds MAX_COMPACT_BUDGET="
-            f"{MAX_COMPACT_BUDGET}: the (bq, bn, budget) one-hot scatter "
-            "block would not fit VMEM — use the jnp reference "
-            "(use_pallas=False / compaction='scan') for larger budgets")
+    if budget < 1:
+        raise ValueError(f"budget must be positive, got {budget}")
     q = windows.shape[0]
-    # the one-hot scatter block is (bq, bn, budget) int32 in VMEM: keep the
-    # budget axis lane-aligned
+    # lane-aligned output columns, filled one chunk per pass over the
+    # record tiles: any budget fits, VMEM holds one (chunk, bn) block
     bud = max(128, -(-budget // 128) * 128)
+    chunk = min(bud, COMPACT_CHUNK)
+    bud = -(-bud // chunk) * chunk
     windows, bounds, (leaf_mbrs, rec_mbrs) = _pad_inputs(
         windows, bounds, bq, bn, leaf_mbrs, rec_mbrs)
-    qp, np_ = windows.shape[0], leaf_mbrs.shape[0]
+    qp, np_ = windows.shape[0], leaf_mbrs.shape[1]
     slots, counts = pl.pallas_call(
-        partial(_compact_kernel, bn=bn, budget=bud, prefilter=prefilter),
-        grid=_grids(qp, np_, bq, bn),
+        partial(_compact_kernel, bn=bn, chunk=chunk, prefilter=prefilter),
+        grid=(pl.cdiv(qp, bq), bud // chunk, pl.cdiv(np_, bn)),
         in_specs=[
-            pl.BlockSpec((bq, 4), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 4), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn, 4), lambda i, j: (j, 0)),
+            pl.BlockSpec((bq, 4), lambda i, c, j: (i, 0)),
+            pl.BlockSpec((bq, 2), lambda i, c, j: (i, 0)),
+            pl.BlockSpec((4, bn), lambda i, c, j: (0, j)),
+            pl.BlockSpec((4, bn), lambda i, c, j: (0, j)),
         ],
         out_specs=(
-            pl.BlockSpec((bq, bud), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq,), lambda i, j: (i,)),
+            pl.BlockSpec((bq, chunk), lambda i, c, j: (i, c)),
+            pl.BlockSpec((bq, 1), lambda i, c, j: (i, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((qp, bud), jnp.int32),
-            jax.ShapeDtypeStruct((qp,), jnp.int32),
+            jax.ShapeDtypeStruct((qp, 1), jnp.int32),
         ),
         cost_estimate=_cost_estimate("compact", qp, np_, bud),
         interpret=interpret,
     )(windows, bounds, leaf_mbrs, rec_mbrs)
-    return slots[:q, :budget], counts[:q]
-
-
-def refine_fused_pallas(windows: jax.Array, probe_w: jax.Array,
-                        qkeys: jax.Array, keys: jax.Array, recs: jax.Array,
-                        leaf_i: jax.Array, leaf_f: jax.Array,
-                        node_i: jax.Array, node_f: jax.Array,
-                        codes: jax.Array, pw: jax.Array, pod_i: jax.Array,
-                        pool: jax.Array, leaf_mbrs: jax.Array,
-                        rec_mbrs: jax.Array, *, budget: int, prefilter: str,
-                        predicate, augment: bool, search_steps: int,
-                        depth: int, num_buckets: int, bq: int = DEFAULT_BQ,
-                        bn: int = COMPACT_BN, interpret: bool = False):
-    """One-dispatch probe + compact + exact refine.
-
-    Per-query inputs (Q rows): ``windows``/``probe_w`` (Q, 4) f32 raw and
-    relation-padded windows, ``qkeys`` (Q, 4) i32 pre-augmentation
-    ``[zmin_hi, zmin_lo, ub_hi, ub_lo]`` query keys. VMEM-resident tables
-    (packed by ``core.device._fused_operands``): ``keys`` (N, 2) i32 limb
-    pairs, ``recs`` (N, 1) i32 record ids, ``leaf_i`` (L+1, 5) i32
-    ``[start, dlo_hi, dlo_lo, k0_hi, k0_lo]``, ``leaf_f`` (L+1, 2) f32
-    ``[slope, icpt]``, ``node_i`` (M, 4) i32 ``[dlo_hi, dlo_lo, fanout,
-    child_base]``, ``node_f`` (M, 1) f32 scale, ``codes`` (C, 1) i32,
-    ``pw`` (P, 4) i32 ``[zmax_hi, zmax_lo, sufmin_hi, sufmin_lo]``,
-    ``pod_i`` (R, 4) i32 ``[off, nv, kind, bucket]`` pod headers and
-    ``pool`` (V, 2) f32 vertex pods. ``leaf_mbrs``/``rec_mbrs`` are the
-    (N, 4) slot-aligned MBR tables, streamed in (BN, 4) tiles.
-
-    ``predicate`` is the relation's exact test ``(window, verts, nv, kind)
-    -> bool`` already bound to ``xp=jnp``; ``augment`` statically enables
-    the in-kernel suffix-min search (pass False when the relation does not
-    augment OR the piecewise table is empty).
-
-    Returns ``(hits (Q, budget) i32 record ids [-1 padded], counts (Q,)
-    i32)`` — identical to ``batch_query``'s two-stage paths, except the
-    fused path is capless so a negative count is ALWAYS budget overflow
-    encoding the total MBR-survivor count (``-(survivors) - 1``).
-    """
-    if prefilter not in ("intersects", "contains"):
-        raise ValueError(f"unsupported prefilter {prefilter!r}")
-    if not 0 < budget <= MAX_COMPACT_BUDGET:
-        raise ValueError(
-            f"budget {budget} outside (0, MAX_COMPACT_BUDGET="
-            f"{MAX_COMPACT_BUDGET}]: the fused kernel is two-stage only and "
-            "its one-hot scatter block must fit VMEM — use the staged "
-            "batch_query for budget 0 or larger budgets")
-    q, n = windows.shape[0], keys.shape[0]
-    lanes = max(128, -(-budget // 128) * 128)   # lane-aligned survivor block
-    qp, np_ = (-q) % bq, (-n) % bn
-    if qp:
-        # padded query rows carry zero windows and zero keys: an empty
-        # [lower_bound(0), lower_bound(0)) run, no survivors, sliced off
-        windows = jnp.pad(windows, ((0, qp), (0, 0)))
-        probe_w = jnp.pad(probe_w, ((0, qp), (0, 0)))
-        qkeys = jnp.pad(qkeys, ((0, qp), (0, 0)))
-    if np_:
-        # padded slots: sorted-order-preserving max keys, record 0, MBRs
-        # that intersect nothing (and can never contain a window)
-        keys = jnp.pad(keys, ((0, np_), (0, 0)), constant_values=_LIMB_MAX)
-        recs = jnp.pad(recs, ((0, np_), (0, 0)))
-        leaf_mbrs = jnp.pad(leaf_mbrs, ((0, np_), (0, 0)),
-                            constant_values=_NEVER)
-        rec_mbrs = jnp.pad(rec_mbrs, ((0, np_), (0, 0)),
-                           constant_values=_NEVER)
-    qpad, npad = windows.shape[0], keys.shape[0]
-
-    def full(a):
-        return pl.BlockSpec(a.shape, lambda i, j, nd=a.ndim: (0,) * nd)
-
-    hits, counts, _bounds = pl.pallas_call(
-        partial(_fused_kernel, bn=bn, budget=budget, lanes=lanes,
-                prefilter=prefilter, predicate=predicate, augment=augment,
-                search_steps=search_steps, depth=depth,
-                num_buckets=num_buckets),
-        grid=_grids(qpad, npad, bq, bn),
-        in_specs=[
-            pl.BlockSpec((bq, 4), lambda i, j: (i, 0)),   # raw windows
-            pl.BlockSpec((bq, 4), lambda i, j: (i, 0)),   # probe windows
-            pl.BlockSpec((bq, 4), lambda i, j: (i, 0)),   # query z-keys
-            full(keys), full(recs), full(leaf_i), full(leaf_f),
-            full(node_i), full(node_f), full(codes), full(pw),
-            full(pod_i), full(pool),
-            pl.BlockSpec((bn, 4), lambda i, j: (j, 0)),   # leaf MBR tile
-            pl.BlockSpec((bn, 4), lambda i, j: (j, 0)),   # record MBR tile
-        ],
-        out_specs=(
-            pl.BlockSpec((bq, lanes), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq,), lambda i, j: (i,)),
-            pl.BlockSpec((bq, 2), lambda i, j: (i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((qpad, lanes), jnp.int32),
-            jax.ShapeDtypeStruct((qpad,), jnp.int32),
-            # probe bounds live in a revisited output block (cross-grid-step
-            # state, like the running count); callers discard them
-            jax.ShapeDtypeStruct((qpad, 2), jnp.int32),
-        ),
-        cost_estimate=_cost_estimate("fused", qpad, npad, budget),
-        interpret=interpret,
-    )(windows, probe_w, qkeys, keys, recs, leaf_i, leaf_f, node_i, node_f,
-      codes, pw, pod_i, pool, leaf_mbrs, rec_mbrs)
-    return hits[:q, :budget], counts[:q]
+    return slots[:q, :budget], counts[:q, 0]
 
 
 def _knn_topk_kernel(d_ref, id_ref, outd_ref, outi_ref, *, k):
@@ -569,24 +309,40 @@ def _knn_topk_kernel(d_ref, id_ref, outd_ref, outi_ref, *, k):
 
     Round j extracts the minimum (distance, id) pair — the minimum distance,
     then the minimum id among its ties, matching the ``geometry.rank_knn``
-    ordering contract — stores it at output column j and masks the selected
-    lane to +inf. O(k·B) work per query row: cheaper than a full O(B log B)
-    sort whenever k << B (the large-budget regime this kernel targets)."""
-    d = d_ref[...]            # (BQ, B) f32 squared distances, +inf padded
-    ids = id_ref[...]         # (BQ, B) i32 record ids, INT32_MAX padded
-    outd_ref[...] = jnp.full_like(outd_ref[...], jnp.inf)
-    outi_ref[...] = jnp.full_like(outi_ref[...], jnp.int32(2**31 - 1))
+    ordering contract — and retires exactly one lane holding it (the lowest
+    such lane, so a repeated pair is emitted as often as ``lax.sort`` would
+    emit it). The pair lands in output column j through an iota select on
+    the (BQ, KP) accumulators, and the whole block is written once after the
+    loop: Mosaic cannot store one lane at a dynamic column. O(k·B) work per
+    query row: cheaper than a full O(B log B) sort whenever k << B (the
+    large-budget regime this kernel targets)."""
+    big = jnp.int32(2**31 - 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, id_ref.shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, outd_ref.shape, 1)
 
-    def round_(j, dw):
+    def round_(j, st):
+        dw, iw, outd, outi = st
         m = jnp.min(dw, axis=1, keepdims=True)               # (BQ, 1)
         tie = dw == m
-        mid = jnp.min(jnp.where(tie, ids, jnp.int32(2**31 - 1)),
-                      axis=1, keepdims=True)
-        pl.store(outd_ref, (slice(None), pl.dslice(j, 1)), m)
-        pl.store(outi_ref, (slice(None), pl.dslice(j, 1)), mid)
-        return jnp.where(tie & (ids == mid), jnp.float32(jnp.inf), dw)
+        mid = jnp.min(jnp.where(tie, iw, big), axis=1, keepdims=True)
+        first = jnp.min(jnp.where(tie & (iw == mid), lane, big),
+                        axis=1, keepdims=True)
+        gone = lane == first
+        here = col == j
+        # a retired lane becomes a dead one, (inf, INT32_MAX), so rounds
+        # past the live candidates emit exactly the sort's padding pairs
+        return (jnp.where(gone, jnp.float32(jnp.inf), dw),
+                jnp.where(gone, big, iw),
+                jnp.where(here, m, outd), jnp.where(here, mid, outi))
 
-    jax.lax.fori_loop(0, k, round_, d)
+    _, _, outd, outi = jax.lax.fori_loop(
+        0, k, round_,
+        (d_ref[...],          # (BQ, B) f32 squared distances, +inf padded
+         id_ref[...],         # (BQ, B) i32 record ids, INT32_MAX padded
+         jnp.full(outd_ref.shape, jnp.inf, jnp.float32),
+         jnp.full(outi_ref.shape, big, jnp.int32)))
+    outd_ref[...] = outd
+    outi_ref[...] = outi
 
 
 def knn_topk_pallas(d: jax.Array, ids: jax.Array, k: int,
@@ -626,29 +382,6 @@ def knn_topk_pallas(d: jax.Array, ids: jax.Array, k: int,
     return outd[:q, :k], outi[:q, :k]
 
 
-def fused_vmem_bytes(n_slots: int, n_leaves: int, n_nodes: int, n_codes: int,
-                     n_pieces: int, n_records: int, pool_rows: int,
-                     budget: int, max_width: int, bq: int = DEFAULT_BQ,
-                     bn: int = COMPACT_BN) -> int:
-    """Worst-case VMEM residency of one fused-kernel grid step: the
-    replicated model tables + pods (resident for the whole dispatch), the
-    streamed MBR tiles, the one-hot scatter block and the widest-bucket
-    vertex gather. The engine compares this against ``FUSED_VMEM_LIMIT``
-    and falls back to the staged pipeline when the store outgrows it."""
-    lanes = max(128, -(-budget // 128) * 128)
-    resident = (n_slots * 12                 # keys (2) + recs (1) int32
-                + (n_leaves + 1) * 28        # leaf_i (5) + leaf_f (2)
-                + n_nodes * 20               # node_i (4) + node_f (1)
-                + n_codes * 4
-                + n_pieces * 16              # pw (4) int32
-                + n_records * 16             # pod headers (4) int32
-                + pool_rows * 8)             # (V, 2) f32 vertex pods
-    streamed = 2 * bn * 16 + bq * 56         # MBR tiles + query rows/bounds
-    scatter = bq * bn * lanes * 4            # one-hot compaction block
-    gather = bq * lanes * (max_width * 8 + 16)   # widest-bucket pod gather
-    return resident + streamed + scatter + gather
-
-
 # ---------------------------------------------------------------------------
 # Analytic cost model (compiler CostEstimate + roofline_report --kernels)
 # ---------------------------------------------------------------------------
@@ -657,44 +390,20 @@ def refine_cost(kind: str, q: int, n: int, budget: int = 0,
                 bn: int = DEFAULT_BN) -> dict:
     """Bytes/flops model of one kernel invocation.
 
-    ``kind``: "mask" | "count" | "compact" | "exact" | "fused" | "knn" —
+    ``kind``: "mask" | "count" | "compact" | "exact" | "knn" —
     "exact" models the downstream exact-shape refinement stage over the
     compacted (Q, budget) survivors, so the roofline report covers the full
     compact+refine pipeline, not just candidate counting; "knn" models the
     device top-k ranking stage (``knn_topk_pallas`` /
     ``core.device.batch_knn_rank``): exact-distance evaluation over ``n``
     candidate columns at gather width ``verts`` plus the k-round partial
-    selection, where ``budget`` is k; "fused" models
-    the one-dispatch probe+compact+exact kernel: the compact and exact
-    terms plus one key-limb stream per query tile for the in-kernel binary
-    searches, MINUS the (Q, budget) survivor-slot and (Q, 2) bounds HBM
-    round trips the staged pipeline pays between dispatches. ``verts`` is
+    selection, where ``budget`` is k. ``verts`` is
     the gather width of the batch's widest surviving pow2 width-bucket (the
     vertex-pool pods gather per-bucket, see ``core.device.VertexPods``),
     NOT the store-wide dense padding — callers should pass ``pow2ceil`` of
     the surviving ring width they expect.
     """
     tiles_q = -(-q // bq)
-    if kind == "fused":
-        c = refine_cost("compact", q, n, budget, bq=bq, bn=bn)
-        e = refine_cost("exact", q, n, budget, verts=verts, bq=bq, bn=bn)
-        # staged-pipeline intermediates that never touch HBM in one
-        # dispatch: compact writes + exact reads of the (Q, budget) slots,
-        # plus the (Q, 2) probe bounds each stage re-reads
-        saved = q * (2.0 * max(budget, 1) + 5.0) * 4.0
-        # in-kernel probe: the keys limb pairs are VMEM-resident for the
-        # whole dispatch (constant-index BlockSpec — fetched from HBM once,
-        # not per query tile); ~2 searches x (steps ~ 18) x ~12 flops of
-        # limb compares + model arithmetic per query
-        probe_bytes = n * 8.0 + q * 32.0
-        probe_flops = q * 2.0 * 18.0 * 12.0
-        return {
-            "flops": c["flops"] + e["flops"] + probe_flops,
-            "bytes_accessed": max(
-                c["bytes_accessed"] + e["bytes_accessed"]
-                + probe_bytes - saved, 0.0),
-            "transcendentals": 0,
-        }
     if kind == "exact":
         # per-bucket pod gather + predicate over compacted survivors:
         # verts = widest surviving bucket width, (verts, 2) f32 rings plus
@@ -715,8 +424,10 @@ def refine_cost(kind: str, q: int, n: int, budget: int = 0,
         flops = q * n * verts * 40 + q * k * n * 3.0
         return {"flops": float(flops), "bytes_accessed": float(bytes_accessed),
                 "transcendentals": float(q * k)}     # sqrt on the k winners
-    # streaming kernels: each query row-tile streams the full MBR table(s)
-    streams = 2 if kind == "compact" else 1
+    # streaming kernels: each query row-tile streams the full MBR table(s),
+    # the compact kernel once per COMPACT_CHUNK output columns
+    streams = (2 * -(-max(budget, 1) // COMPACT_CHUNK) if kind == "compact"
+               else 1)
     bytes_accessed = tiles_q * n * 16 * streams + q * 24
     flops = q * n * 10.0          # interval + MBR comparisons per pair
     if kind == "mask":

@@ -236,6 +236,9 @@ def main(argv=None) -> int:
     lm.add_argument("--seed", type=int, default=0)
 
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     return main_spatial(args) if args.mode == "spatial" else main_lm(args)
 
 

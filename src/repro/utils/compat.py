@@ -1,35 +1,27 @@
-"""Version compatibility for the jax API surface this repo uses.
+"""The two jax mesh helpers this repo calls everywhere.
 
-The code targets the current jax API (``jax.shard_map``, explicit mesh
-``axis_types``); the container image may ship an older jax (0.4.x) where
-``shard_map`` still lives in ``jax.experimental`` (with ``check_rep`` instead
-of ``check_vma``) and ``jax.make_mesh`` has no ``axis_types``. These two
-wrappers pick whichever spelling exists — use them instead of calling the jax
-functions directly.
+``make_auto_mesh`` builds a mesh with Auto axis types; ``shard_map`` wraps
+``jax.shard_map`` with replication checking off (the call sites return
+per-shard values on purpose). The code targets the installed jax (0.9) only.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_auto_mesh", "shard_map"]
 
 
-def make_auto_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+def make_auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axis types. ``devices`` defaults to
+    ``jax.devices()``; pass described devices to compile for a topology
+    that is not attached."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes), **kw)
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off (the call sites return
-    per-shard values on purpose); falls back to ``jax.experimental``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -514,9 +514,9 @@ def test_delta_side_table_matches_host_loop_patching():
 
 
 def test_compaction_modes_bit_identical():
-    """sort (legacy argsort), scan (jnp reference) and pallas (fused kernel,
-    interpret mode off-TPU) must return bit-identical hits/counts through
-    batch_query, including on odd batch sizes."""
+    """sort (legacy argsort), scan (jnp reference) and the Pallas compact
+    kernel (in interpret mode, asked for by name) must return bit-identical
+    hits/counts through batch_query, including on odd batch sizes."""
     from repro.core.device import batch_query
 
     idx = _build(n=2500, pl=200)
@@ -527,11 +527,11 @@ def test_compaction_modes_bit_identical():
     for rel in ("intersects", "contains", "within", "dwithin:0.003"):
         base = get_relation(rel).base_name()
         outs = {}
-        for mode in ("sort", "scan", "pallas"):
+        for mode in ("sort", "scan", "interpret"):
             h, c = batch_query(snap, wj, *payload, relation=base,
                                cap=1 << 15, exact_budget=64, compaction=mode)
             outs[mode] = (np.asarray(h), np.asarray(c))
-        for mode in ("scan", "pallas"):
+        for mode in ("scan", "interpret"):
             np.testing.assert_array_equal(outs["sort"][0], outs[mode][0])
             np.testing.assert_array_equal(outs["sort"][1], outs[mode][1])
 
@@ -542,7 +542,7 @@ def test_forced_compaction_config_parity():
     idx_auto = _build(n=2000)
     wins = make_query_windows(idx_auto.gs, 0.01, 20, seed=13)
     ref = idx_auto.query(wins, "intersects", backend="device")
-    for mode in ("sort", "scan", "pallas"):
+    for mode in ("sort", "scan", "interpret"):
         idx = SpatialIndex(idx_auto.glin, EngineConfig(compaction=mode))
         res = idx.query(wins, "intersects", backend="device")
         for a, b in zip(res, ref):
@@ -764,7 +764,7 @@ def test_budget_overflow_encodes_survivor_count():
     wins = make_query_windows(idx.gs, 0.01, 6, seed=9)
     wj = jnp.asarray(wins.astype(np.float32))
     surv = {}
-    for mode in ("sort", "scan", "pallas"):
+    for mode in ("sort", "scan", "interpret"):
         _, c = batch_query(snap, wj, *payload, relation="intersects",
                            cap=1 << 15, exact_budget=2, compaction=mode)
         surv[mode] = np.asarray(c)
@@ -797,9 +797,10 @@ def _mbr_survivors(idx, wins):
 def test_budget_ladder_grows_geometrically_then_goes_dense():
     """Survivors past a small exact_budget grow the budget geometrically
     (re-running compaction) instead of dropping straight to the dense path;
-    only survivors past MAX_COMPACT_BUDGET escalate to dense."""
+    only survivors past CAPPED_MAX_BUDGET escalate to dense (the scan
+    compaction here is capped)."""
     import repro.core.engine as eng
-    from repro.kernels.refine import MAX_COMPACT_BUDGET
+    from repro.core.exec import CAPPED_MAX_BUDGET
 
     calls = []
     real_bq = eng.batch_query
@@ -813,7 +814,7 @@ def test_budget_ladder_grows_geometrically_then_goes_dense():
     try:
         eng.batch_query = spy
         # moderately selective: survivors overflow budget=8 but stay well
-        # under MAX_COMPACT_BUDGET -> the ladder must stay two-stage
+        # under CAPPED_MAX_BUDGET -> the ladder must stay two-stage
         wins = make_query_windows(idx.gs, 0.02, 4, seed=3)
         res = idx.query(wins, "intersects", backend="device")
         budgets = [b for _, b in calls]
@@ -826,12 +827,12 @@ def test_budget_ladder_grows_geometrically_then_goes_dense():
             np.testing.assert_array_equal(
                 res[qi], _oracle(idx, w.astype(np.float32), "intersects",
                                  np.float32))
-        # whole-domain covers: survivors ~ N > MAX_COMPACT_BUDGET -> dense
+        # whole-domain covers: survivors ~ N > CAPPED_MAX_BUDGET -> dense
         calls.clear()
         whole = np.repeat(np.array([[0.0, 0.0, 1.0, 1.0]]), 2, axis=0)
         res = idx.query(whole, "covers", backend="device")
         assert calls[-1][1] == 0, calls       # escalated to single-stage
-        assert all(b <= MAX_COMPACT_BUDGET for _, b in calls)
+        assert all(b <= CAPPED_MAX_BUDGET for _, b in calls)
         np.testing.assert_array_equal(
             res[0], _oracle(idx, whole[0].astype(np.float32), "covers",
                             np.float32))

@@ -136,6 +136,43 @@ def test_ladder_escalations_surface_in_stage_stats():
     assert agg["calls"] >= 1 and agg["wall_ms"] > 0.0
 
 
+@pytest.mark.parametrize("capless", [True, False])
+def test_ladder_budget_ceiling_capless_vs_capped(capless):
+    """Capless compaction grows its budget past the cap and past one
+    kernel chunk (up to max_cap); capped compaction drops to the dense path
+    once the budget would pass CAPPED_MAX_BUDGET or the cap."""
+    cfg = EngineConfig(exact_budget=256, max_cap=1 << 20)
+    ladder = qexec.OverflowLadder(cfg, cap=4096, capless=capless)
+    ladder.on_capless_overflow(np.array([-5001, 3]), ladder.use_budget)
+    if capless:
+        assert ladder.use_budget == 8192          # >= cap, still two-stage
+        ladder.on_capless_overflow(np.array([-(1 << 21) - 1]), 8192)
+        assert ladder.use_budget == 0             # past max_cap: dense
+    else:
+        assert ladder.use_budget == 0             # past the capped ceiling
+
+
+def test_capless_device_refine_never_goes_dense():
+    """A window with more MBR survivors than one compact-kernel chunk and a
+    probe run longer than the cap stays on the kernel: the budget grows,
+    the cap does not, no dense attempt runs, and the answer is exact (the
+    dense fallback would need a cap as long as the run, which on a
+    deployment-size store is past max_cap)."""
+    from repro.kernels.refine import COMPACT_CHUNK
+
+    idx = _mixed(n=1800, initial_cap=512, exact_budget=64,
+                 compaction="interpret")
+    lo = idx.gs.mbrs[:, :2].min(axis=0) - 0.01
+    hi = idx.gs.mbrs[:, 2:].max(axis=0) + 0.01
+    w = np.array([[lo[0], lo[1], hi[0], hi[1]]])
+    res = idx.query(w, "intersects", backend="device")
+    np.testing.assert_array_equal(res[0], _oracle(idx, w[0], "intersects"))
+    refine = {s.stage: s for s in res.stages}["refine"]
+    assert refine.note == "compaction=interpret"
+    assert refine.budget > COMPACT_CHUNK and refine.cap == 512
+    assert refine.escalations >= 1
+
+
 # ------------------------------------------------------ delta-patch stage ---
 def test_delta_patch_stage_stats_and_parity():
     """Writes after a publish route through device+delta: the shared patch
@@ -263,6 +300,41 @@ def _run_py(code: str, devices: int = 8) -> str:
                        capture_output=True, text=True, env=env, timeout=900)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-4000:]}"
     return r.stdout
+
+
+def test_sharded_capless_budget_past_chunk():
+    """Sharded windows whose per-shard survivors pass one kernel chunk
+    grow the budget on the compact kernel (interpret mode) and stay exact:
+    no dense attempt, the cap untouched (4 fake CPU devices)."""
+    out = _run_py("""
+        import numpy as np
+        from repro.utils.compat import make_auto_mesh
+        mesh = make_auto_mesh((4, 1), ("data", "model"))
+        from repro.core.datasets import generate
+        from repro.core.geometry import mbrs_of_verts
+        from repro.core.index import GLIN, GLINConfig
+        from repro.core.engine import EngineConfig, SpatialIndex
+
+        gs = generate("mixed", 5000, seed=4)
+        gs.verts = gs.verts.astype(np.float32).astype(np.float64)
+        gs.mbrs = mbrs_of_verts(gs.verts, gs.nverts)
+        idx = SpatialIndex(
+            GLIN.build(gs, GLINConfig(piece_limitation=300)),
+            EngineConfig(mesh=mesh, device_min_batch=1, initial_cap=512,
+                         exact_budget=64, compaction="interpret",
+                         stale_rebuild_min_batch=1, shard_min_records=1))
+        lo = gs.mbrs[:, :2].min(axis=0) - 0.01
+        hi = gs.mbrs[:, 2:].max(axis=0) + 0.01
+        w = np.array([[lo[0], lo[1], hi[0], hi[1]]])
+        s = idx.query(w, "intersects", backend="sharded")
+        h = idx.query(w, "intersects", backend="host")
+        assert np.array_equal(s[0], h[0]) and len(s[0]) == 5000
+        refine = {st.stage: st for st in s.stages}["refine"]
+        assert refine.note == "compaction=interpret", refine.note
+        assert refine.budget > 1024 and refine.cap == 512, refine
+        print("SHARDED-CAPLESS-OK")
+    """, devices=4)
+    assert "SHARDED-CAPLESS-OK" in out
 
 
 def test_sharded_pipeline_parity_and_telemetry():

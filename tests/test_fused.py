@@ -1,16 +1,16 @@
-"""Interpret-mode parity suite for the ONE-dispatch fused query kernel.
+"""Parity suite for the whole-table fused query and the compact kernel.
 
-``batch_query_fused`` executes probe + mask/compact + exact refinement as a
-single kernel launch. These tests pin its three vehicles — the Pallas
-kernel body via interpret mode (the CPU correctness path; same body the TPU
-runs), the single-jit "reference" XLA composition, and the staged
-``batch_query(compaction="scan")`` baseline — bit-identical on hits AND
-counts, including the awkward shapes: odd Q/N (tile padding), empty and
-inverted probe runs, zero-survivor and all-survivor rows, the capless
-``-(survivors) - 1`` budget-overflow encoding, and widest-bucket vertex
-gathers on the heavy-tailed mixed store. The engine-level tests assert the
-3 -> 1 dispatch collapse through the ``StageStats.dispatches`` telemetry
-(not timings) and the planner's fused-selection / fallback rules."""
+``batch_query_fused`` executes probe + capless whole-table mask/compact +
+exact refinement as one XLA program (``EngineConfig.fusion="reference"``).
+These tests pin it, the compact kernel through ``batch_query(...,
+compaction="interpret")`` (the CPU correctness path; same kernel body the
+TPU runs) and the staged ``batch_query(compaction="scan")`` baseline
+bit-identical on hits AND counts, including the awkward shapes: odd Q/N
+(tile padding), empty and inverted probe runs, zero-survivor and
+all-survivor rows, the capless ``-(survivors) - 1`` budget-overflow
+encoding, and widest-bucket vertex gathers on the heavy-tailed mixed store.
+The engine-level tests assert the dispatch telemetry and the planner's
+fused-selection / fallback rules."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -18,14 +18,12 @@ import pytest
 from _oracle import mixed_store
 from repro.core import relations
 from repro.core.datasets import make_query_windows
-from repro.core.device import (_device_relation, _fused_operands,
-                               _raw_query_keys, batch_query,
+from repro.core.device import (_compact_kernel_refine, _device_relation,
+                               batch_query, batch_query_bounds,
                                batch_query_fused, pods_from_store)
 from repro.core.engine import EngineConfig, SpatialIndex
 from repro.core.index import GLIN, GLINConfig
-from repro.kernels import ops
-from repro.kernels.refine import (FUSED_VMEM_LIMIT, MAX_COMPACT_BUDGET,
-                                  fused_vmem_bytes)
+from repro.kernels.refine import COMPACT_CHUNK
 
 # relations spanning both static prefilter shapes, augmentation on/off,
 # probe pads (dwithin) and a host-predicate-heavy case (crosses)
@@ -49,6 +47,13 @@ def _staged_scan(s, wj, pods, gs, base, budget, cap=1024):
                        exact_budget=budget, compaction="scan")
 
 
+def _kernel(s, wj, pods, gs, base, budget, cap=1024):
+    """The compact kernel (interpret mode) inside the staged program."""
+    mb = jnp.asarray(gs.mbrs.astype(np.float32))
+    return batch_query(s, wj, pods, mb, relation=base, cap=cap,
+                       exact_budget=budget, compaction="interpret")
+
+
 def _ids(hits):
     return [np.sort(r[r >= 0]) for r in np.asarray(hits)]
 
@@ -65,9 +70,8 @@ def test_fused_parity_odd_shapes(mixed, relation, q):
     wins = make_query_windows(gs, 0.004, q, seed=4)
     wj = jnp.asarray(wins.astype(np.float32))
     h_ref, c_ref = batch_query_fused(s, wj, pods, relation=base,
-                                     exact_budget=64, mode="reference")
-    h_int, c_int = batch_query_fused(s, wj, pods, relation=base,
-                                     exact_budget=64, mode="interpret")
+                                     exact_budget=64)
+    h_int, c_int = _kernel(s, wj, pods, gs, base, 64)
     h_scan, c_scan = _staged_scan(s, wj, pods, gs, base, 64)
     np.testing.assert_array_equal(np.asarray(c_int), np.asarray(c_ref))
     np.testing.assert_array_equal(np.asarray(c_int), np.asarray(c_scan))
@@ -90,10 +94,9 @@ def test_fused_budget_overflow_signalling(mixed):
     ], np.float32)
     wj = jnp.asarray(wins)
     outs = {
-        "interpret": batch_query_fused(s, wj, pods, relation="intersects",
-                                       exact_budget=8, mode="interpret"),
+        "interpret": _kernel(s, wj, pods, gs, "intersects", 8),
         "reference": batch_query_fused(s, wj, pods, relation="intersects",
-                                       exact_budget=8, mode="reference"),
+                                       exact_budget=8),
         "scan": _staged_scan(s, wj, pods, gs, "intersects", 8, cap=2048),
     }
     counts = {k: np.asarray(c) for k, (h, c) in outs.items()}
@@ -117,9 +120,7 @@ def test_fused_zero_and_all_survivor_rows(mixed):
         [hi[0] + 1, hi[1] + 1, hi[0] + 2, hi[1] + 2],   # zero survivors
         [lo[0], lo[1], hi[0], hi[1]],                   # all survivors
     ], np.float32)
-    hits, counts = batch_query_fused(s, jnp.asarray(wins), pods,
-                                     relation="intersects",
-                                     exact_budget=512, mode="interpret")
+    hits, counts = _kernel(s, jnp.asarray(wins), pods, gs, "intersects", 512)
     hits, counts = np.asarray(hits), np.asarray(counts)
     assert counts[0] == 0 and (hits[0] == -1).all()
     bf = g.query_bruteforce(wins[1].astype(np.float64), "intersects")
@@ -128,30 +129,25 @@ def test_fused_zero_and_all_survivor_rows(mixed):
 
 
 def test_fused_empty_and_inverted_probe_runs(mixed):
-    """Doctored probe keys through the raw kernel: an inverted run
-    (zmin > ub -> start >= end) and an off-the-end empty run both yield
-    zero survivors; an untouched row is unaffected by its neighbours."""
+    """Doctored probe runs through the compact kernel + exact stage: an
+    inverted run (start > end) and an off-the-end empty run both yield zero
+    survivors; an untouched row is unaffected by its neighbours."""
     gs, g, s, pods = mixed
     rel = _device_relation("contains")      # augment=False: probes stay raw
     wins = make_query_windows(gs, 0.02, 3, seed=7).astype(np.float32)
     wj = jnp.asarray(wins)
-    probe_w = rel.probe_window(wj, xp=jnp)
-    qk = np.stack([np.asarray(a) for a in _raw_query_keys(s, wj, rel)], 1)
-    qk[0] = qk[0][[2, 3, 0, 1]]                   # swap zmin <-> ub
-    qk[1] = [2**30, 0, 2**30, 0]                  # beyond every stored key
-    pod_i = jnp.stack([pods.off, pods.nv, pods.kd, pods.bucket], axis=1)
-    hits, counts = ops.refine_fused(
-        wj, probe_w, jnp.asarray(qk, jnp.int32), *_fused_operands(s),
-        pod_i, pods.pool, s.slot_lmbr, s.slot_rmbr, budget=32,
-        prefilter=rel.prefilter_kind,
-        predicate=lambda w, vv, nn, kk: rel.predicate(w, vv, nn, kk, xp=jnp),
-        augment=False, search_steps=s.search_steps, depth=s.depth,
-        num_buckets=pods.num_buckets, interpret=True)
+    start, end = (np.asarray(a).copy()
+                  for a in batch_query_bounds(s, wj, "contains"))
+    start[0], end[0] = end[0] + 5, start[0]       # inverted run
+    start[1] = end[1] = s.num_slots               # beyond every stored slot
+    hits, counts = _compact_kernel_refine(
+        rel, wj, s, pods, jnp.asarray(start), jnp.asarray(end), 32,
+        interpret=True)
     hits, counts = np.asarray(hits), np.asarray(counts)
     assert counts[0] == 0 and (hits[0] == -1).all()
     assert counts[1] == 0 and (hits[1] == -1).all()
     _, c_ref = batch_query_fused(s, wj, pods, relation="contains",
-                                 exact_budget=32, mode="reference")
+                                 exact_budget=32)
     assert counts[2] == np.asarray(c_ref)[2]
 
 
@@ -165,9 +161,7 @@ def test_fused_widest_bucket_gather(mixed):
     lo = gs.mbrs[:, :2].min(axis=0) - 0.01
     hi = gs.mbrs[:, 2:].max(axis=0) + 0.01
     w = np.array([[lo[0], lo[1], hi[0], hi[1]]], np.float32)
-    hits, counts = batch_query_fused(s, jnp.asarray(w), pods,
-                                     relation="intersects",
-                                     exact_budget=512, mode="interpret")
+    hits, counts = _kernel(s, jnp.asarray(w), pods, gs, "intersects", 512)
     ids = np.sort(np.asarray(hits)[0][np.asarray(hits)[0] >= 0])
     bf = g.query_bruteforce(w[0].astype(np.float64), "intersects")
     np.testing.assert_array_equal(ids, bf)
@@ -179,11 +173,11 @@ def test_fused_input_validation(mixed):
     gs, g, s, pods = mixed
     wj = jnp.asarray(make_query_windows(gs, 0.004, 2, seed=1)
                      .astype(np.float32))
-    with pytest.raises(ValueError, match="mode"):
-        batch_query_fused(s, wj, pods, relation="intersects", mode="turbo")
+    with pytest.raises(ValueError, match="device-native"):
+        batch_query_fused(s, wj, pods, relation="disjoint")
     with pytest.raises(ValueError, match="exact_budget"):
         batch_query_fused(s, wj, pods, relation="intersects",
-                          exact_budget=0, mode="reference")
+                          exact_budget=0)
 
 
 # ---------------------------------------------------------------------------
@@ -198,9 +192,9 @@ def _engine(fusion, n=250, **eng):
 
 
 def test_engine_fused_one_dispatch():
-    """The headline: fusion collapses the staged refine's 3 dispatches to 1,
+    """Fused and staged refines are each one jitted program per attempt,
     asserted via telemetry, with identical results."""
-    idx = _engine("interpret")
+    idx = _engine("reference")
     wins = make_query_windows(idx.gs, 0.004, 17, seed=5)
     res = idx.query(wins, "intersects", backend="device")
     refine = {st.stage: st for st in res.stages}["refine"]
@@ -213,31 +207,34 @@ def test_engine_fused_one_dispatch():
     off = _engine("off")
     res_off = off.query(wins, "intersects", backend="device")
     r_off = {st.stage: st for st in res_off.stages}["refine"]
-    assert r_off.impl == "device" and r_off.dispatches == 3
+    assert r_off.impl == "device" and r_off.dispatches == 1
     for a, b in zip(res.ids, res_off.ids):
         np.testing.assert_array_equal(a, b)
 
 
 @pytest.mark.parametrize("relation", ["intersects", "within", "disjoint"])
 def test_engine_fused_matches_staged(relation):
-    """End-to-end ids agree across fusion modes for plain, contains-shaped
-    and complement-finished relations."""
+    """End-to-end ids agree across the whole-table fused refine, the staged
+    compact kernel (interpret mode) and the staged scan, for plain,
+    contains-shaped and complement-finished relations."""
     wins = None
     got = {}
-    for fusion in ("interpret", "reference", "off"):
-        idx = _engine(fusion)
+    for name, fusion, comp in (("reference", "reference", None),
+                               ("kernel", "off", "interpret"),
+                               ("off", "off", None)):
+        idx = _engine(fusion, compaction=comp)
         if wins is None:
             wins = make_query_windows(idx.gs, 0.004, 9, seed=6)
-        got[fusion] = idx.query(wins, relation, backend="device").ids
-    for fusion in ("interpret", "reference"):
-        for a, b in zip(got[fusion], got["off"]):
+        got[name] = idx.query(wins, relation, backend="device").ids
+    for name in ("reference", "kernel"):
+        for a, b in zip(got[name], got["off"]):
             np.testing.assert_array_equal(a, b)
 
 
 def test_engine_fused_overflow_ladder():
-    """Budget overflow inside the fused kernel walks the SAME OverflowLadder
+    """Budget overflow inside the fused path walks the SAME OverflowLadder
     (capless: no disambiguating bounds probe is spent) and ends exact."""
-    idx = _engine("interpret", exact_budget=4)
+    idx = _engine("reference", exact_budget=4)
     lo = idx.gs.mbrs[:, :2].min(axis=0) - 0.01
     hi = idx.gs.mbrs[:, 2:].max(axis=0) + 0.01
     w = np.array([[lo[0], lo[1], hi[0], hi[1]]])
@@ -251,15 +248,15 @@ def test_engine_fused_overflow_ladder():
 
 
 def test_engine_explain_shows_fused():
-    idx = _engine("interpret")
+    idx = _engine("reference")
     wins = make_query_windows(idx.gs, 0.004, 8, seed=2)
     text = idx.explain(wins, "intersects")
-    assert "fused one-kernel refine" in text
+    assert "fused whole-table refine" in text
     assert "impl=fused" in text
     assert idx.plan(wins, "intersects").fused
     off = _engine("off")
     assert not off.plan(wins, "intersects").fused
-    assert "fused one-kernel refine" not in off.explain(wins, "intersects")
+    assert "fused whole-table refine" not in off.explain(wins, "intersects")
 
 
 def test_custom_prefilter_falls_back_to_staged():
@@ -271,7 +268,7 @@ def test_custom_prefilter_falls_back_to_staged():
         mbr_prefilter=base.mbr_prefilter, prefilter_kind="custom")
     relations.register_relation(custom)
     try:
-        idx = _engine("interpret")
+        idx = _engine("reference")
         wins = make_query_windows(idx.gs, 0.004, 8, seed=2)
         assert not idx.plan(wins, "_test_custom").fused
         res = idx.query(wins, "_test_custom", backend="device")
@@ -284,44 +281,42 @@ def test_custom_prefilter_falls_back_to_staged():
         pods, _ = idx._device_payload(idx._snapshot_recs)
         with pytest.raises(ValueError, match="custom"):
             batch_query_fused(s, jnp.asarray(wins.astype(np.float32)),
-                              pods, relation="_test_custom",
-                              mode="reference")
+                              pods, relation="_test_custom")
     finally:
         relations.RELATIONS.pop("_test_custom", None)
         relations._BOUND.clear()
 
 
 def test_fusion_mode_resolution():
-    """_fusion_mode: the single gate deciding kernel vs staged."""
-    idx = _engine("interpret")
-    assert idx._fusion_mode("intersects") == "interpret"
-    # budget outside (0, MAX_COMPACT_BUDGET] -> staged (dense/oversized)
+    """_fusion_mode: the single gate deciding whole-table vs staged. The
+    fused path is capless, so only a dense escalation (budget 0) leaves
+    it; the compact kernel is a compaction of the staged path, not a
+    fusion mode."""
+    idx = _engine("reference")
+    assert idx._fusion_mode("intersects") == "reference"
     assert idx._fusion_mode("intersects", budget=0) is None
     assert idx._fusion_mode("intersects",
-                            budget=MAX_COMPACT_BUDGET + 1) is None
-    assert idx._fusion_mode("intersects",
-                            budget=MAX_COMPACT_BUDGET) == "interpret"
+                            budget=4 * COMPACT_CHUNK) == "reference"
     off = _engine("off")
     assert off._fusion_mode("intersects") is None
-    bogus = _engine("warp9")
-    with pytest.raises(ValueError, match="fusion"):
-        bogus._fusion_mode("intersects")
+    assert _engine(None)._fusion_mode("intersects") is None
+    for bogus in ("warp9", "pallas", "interpret"):
+        with pytest.raises(ValueError, match="fusion"):
+            _engine(bogus)._fusion_mode("intersects")
 
 
 def test_fusion_vmem_envelope_falls_back():
-    """When the resident tables cannot fit the kernel's VMEM envelope the
-    planner keeps the plan fused (it cannot know the budget the ladder will
-    settle) but the stage falls back to staged execution at run time."""
-    idx = _engine("interpret")
-    snap = idx.snapshot()
-    pods, _ = idx._device_payload(idx._snapshot_recs)
-    assert idx._fusion_mode("intersects", budget=64,
-                            snap=snap, pods=pods) == "interpret"
-    est = fused_vmem_bytes(
-        n_slots=snap.num_slots, n_leaves=snap.leaf_start.shape[0],
-        n_nodes=max(snap.node_dlo_hi.shape[0], 1),
-        n_codes=max(snap.child_codes.shape[0], 1),
-        n_pieces=max(snap.pw_zmax_hi.shape[0], 1),
-        n_records=snap.recs.shape[0], pool_rows=pods.pool.shape[0],
-        budget=64, max_width=1 << (pods.num_buckets - 1))
-    assert 0 < est <= FUSED_VMEM_LIMIT
+    """No store size and no budget below max_cap leaves the whole-table
+    path: survivors past ``COMPACT_CHUNK`` grow the budget and stay fused
+    and exact, with no dense attempt."""
+    idx = _engine("reference", n=1500, exact_budget=4)
+    lo = idx.gs.mbrs[:, :2].min(axis=0) - 0.01
+    hi = idx.gs.mbrs[:, 2:].max(axis=0) + 0.01
+    w = np.array([[lo[0], lo[1], hi[0], hi[1]]])  # 1500 survivors > 1024
+    res = idx.query(w, "intersects", backend="device")
+    refine = {st.stage: st for st in res.stages}["refine"]
+    assert refine.impl == "fused"
+    assert refine.note == "fusion=reference"
+    assert refine.budget > COMPACT_CHUNK
+    np.testing.assert_array_equal(
+        res.ids[0], idx.glin.query_bruteforce(w[0], "intersects"))

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.refine import COMPACT_CHUNK
 
 RNG = np.random.default_rng(0)
 
@@ -14,7 +15,7 @@ RNG = np.random.default_rng(0)
 def test_morton_sweep(n):
     qx = jnp.asarray(RNG.integers(0, 2**30, n), jnp.int32)
     qy = jnp.asarray(RNG.integers(0, 2**30, n), jnp.int32)
-    hi, lo = ops.morton_encode(qx, qy)
+    hi, lo = ops.morton_encode(qx, qy, interpret=True)
     rhi, rlo = ref.morton_ref(qx, qy)
     np.testing.assert_array_equal(np.asarray(hi), np.asarray(rhi))
     np.testing.assert_array_equal(np.asarray(lo), np.asarray(rlo))
@@ -31,10 +32,10 @@ def test_refine_sweep(q, n):
     hi = RNG.integers(n // 2, n, q).astype(np.int32)
     bounds = jnp.asarray(np.stack([lo, hi], 1))
     wins_j, mbrs_j = jnp.asarray(wins), jnp.asarray(mbrs)
-    m = ops.refine_mask(wins_j, bounds, mbrs_j)
+    m = ops.refine_mask(wins_j, bounds, mbrs_j, interpret=True)
     mr = ref.refine_mask_ref(wins_j, bounds, mbrs_j)
     np.testing.assert_array_equal(np.asarray(m), np.asarray(mr))
-    c = ops.refine_count(wins_j, bounds, mbrs_j)
+    c = ops.refine_count(wins_j, bounds, mbrs_j, interpret=True)
     np.testing.assert_array_equal(np.asarray(c), np.asarray(m).sum(1))
 
 
@@ -58,7 +59,7 @@ def test_refine_compact_sweep(q, n, prefilter):
     wins, bounds, lmbrs, rmbrs = _compact_case(q, n)
     for budget in (8, 64):
         s, c = ops.refine_compact(wins, bounds, lmbrs, rmbrs, budget=budget,
-                                  prefilter=prefilter)
+                                  prefilter=prefilter, interpret=True)
         sr, cr = ref.refine_compact_ref(wins, bounds, lmbrs, rmbrs, budget,
                                         prefilter)
         np.testing.assert_array_equal(np.asarray(s), np.asarray(sr))
@@ -78,7 +79,8 @@ def test_refine_compact_empty_runs_and_extremes():
     b[3] = (0, n)
     wins_j, b_j = jnp.asarray(wins), jnp.asarray(b)
     budget = 1024                        # >= n: nothing truncated
-    s, c = ops.refine_compact(wins_j, b_j, lmbrs, rmbrs, budget=budget)
+    s, c = ops.refine_compact(wins_j, b_j, lmbrs, rmbrs, budget=budget,
+                              interpret=True)
     sr, cr = ref.refine_compact_ref(wins_j, b_j, lmbrs, rmbrs, budget)
     np.testing.assert_array_equal(np.asarray(s), np.asarray(sr))
     np.testing.assert_array_equal(np.asarray(c), np.asarray(cr))
@@ -102,11 +104,41 @@ def test_refine_compact_budget_overflow_signalling():
     bounds = jnp.asarray(np.tile([0, n], (q, 1)).astype(np.int32))
     budget = 16
     s, c = ops.refine_compact(jnp.asarray(wins), bounds, jnp.asarray(lmbrs),
-                              jnp.asarray(rmbrs), budget=budget)
+                              jnp.asarray(rmbrs), budget=budget,
+                              interpret=True)
     s, c = np.asarray(s), np.asarray(c)
     assert (c == n).all() and (c > budget).all()
     for row in s:
         np.testing.assert_array_equal(row, np.arange(budget))
+
+
+# budgets past one output chunk: the kernel fills them one chunk per pass
+# over the record tiles (truncated past the budget, exact below it)
+@pytest.mark.parametrize("budget", [COMPACT_CHUNK + 1, 2 * COMPACT_CHUNK,
+                                    3000])
+def test_refine_compact_multi_chunk_budgets(budget):
+    q, n = 4, 4100
+    rng = np.random.default_rng(11)
+    rmbrs = rng.uniform(0, 0.95, (n, 4)).astype(np.float32)
+    rmbrs[:, 2:] = rmbrs[:, :2] + 0.01
+    lmbrs = rmbrs + np.array([-0.02, -0.02, 0.02, 0.02], np.float32)
+    wins = np.array([[-1, -1, 2, 2],           # every record survives
+                     [0.1, 0.1, 0.8, 0.8],     # about half survive
+                     [-1, -1, 2, 2],           # a run starting mid-table
+                     [0.5, 0.5, 0.52, 0.52]],  # a handful
+                    np.float32)
+    bounds = np.tile([0, n], (q, 1)).astype(np.int32)
+    bounds[2] = (1500, n - 3)
+    args = (jnp.asarray(wins), jnp.asarray(bounds), jnp.asarray(lmbrs),
+            jnp.asarray(rmbrs))
+    s, c = ops.refine_compact(*args, budget=budget, interpret=True)
+    sr, cr = ref.refine_compact_ref(*args, budget)
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(cr))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(sr))
+    c = np.asarray(c)
+    assert c[0] == n and c[1] > COMPACT_CHUNK    # several chunks filled
+    kept = (np.asarray(s) >= 0).sum(axis=1)
+    np.testing.assert_array_equal(kept, np.minimum(c, budget))
 
 
 @pytest.mark.parametrize("q,n", [(3, 100), (13, 999), (30, 2047)])
@@ -114,10 +146,10 @@ def test_refine_mask_count_internal_padding(q, n):
     """mask/count accept shapes that are NOT tile multiples (the kernels pad
     internally; callers stopped pre-padding)."""
     wins, bounds, _, mbrs = _compact_case(q, n, seed=7)
-    m = ops.refine_mask(wins, bounds, mbrs)
+    m = ops.refine_mask(wins, bounds, mbrs, interpret=True)
     mr = ref.refine_mask_ref(wins, bounds, mbrs)
     np.testing.assert_array_equal(np.asarray(m), np.asarray(mr))
-    c = ops.refine_count(wins, bounds, mbrs)
+    c = ops.refine_count(wins, bounds, mbrs, interpret=True)
     np.testing.assert_array_equal(np.asarray(c), np.asarray(m).sum(1))
 
 
@@ -133,7 +165,8 @@ def test_flash_attention_sweep(dtype, tol, s, d, hq, hkv, window, bq):
     q = jnp.asarray(RNG.normal(0, 1, (b, hq, s, d)), dtype)
     k = jnp.asarray(RNG.normal(0, 1, (b, hkv, s, d)), dtype)
     v = jnp.asarray(RNG.normal(0, 1, (b, hkv, s, d)), dtype)
-    o = ops.flash_attention(q, k, v, window=window, bq=bq, bk=bq)
+    o = ops.flash_attention(q, k, v, window=window, bq=bq, bk=bq,
+                            interpret=True)
     r = ref.attention_ref(q, k, v, window=window)
     err = float(jnp.max(jnp.abs(o.astype(jnp.float32) - r.astype(jnp.float32))))
     assert err < tol, err
@@ -150,7 +183,7 @@ def test_ssd_sweep(s, h, p, n, chunk):
     a = jnp.asarray(-RNG.uniform(0.1, 1.0, h), jnp.float32)
     bm = jnp.asarray(RNG.normal(0, 1, (b, s, n)), jnp.float32)
     cm = jnp.asarray(RNG.normal(0, 1, (b, s, n)), jnp.float32)
-    y = ops.ssd_scan(x, dt, a, bm, cm, chunk=chunk)
+    y = ops.ssd_scan(x, dt, a, bm, cm, chunk=chunk, interpret=True)
     r = ref.ssd_ref(x, dt, a, bm, cm)
     np.testing.assert_allclose(np.asarray(y), np.asarray(r), atol=2e-4, rtol=1e-3)
 
@@ -163,7 +196,8 @@ def test_ssd_chunk_invariance():
     a = jnp.asarray(-RNG.uniform(0.1, 1.0, h), jnp.float32)
     bm = jnp.asarray(RNG.normal(0, 1, (b, s, n)), jnp.float32)
     cm = jnp.asarray(RNG.normal(0, 1, (b, s, n)), jnp.float32)
-    outs = [np.asarray(ops.ssd_scan(x, dt, a, bm, cm, chunk=c))
+    outs = [np.asarray(ops.ssd_scan(x, dt, a, bm, cm, chunk=c,
+                                       interpret=True))
             for c in (32, 64, 96, 192)]
     for o in outs[1:]:
         np.testing.assert_allclose(o, outs[0], atol=2e-4, rtol=1e-3)
@@ -179,7 +213,7 @@ def test_xla_path_matches_kernel():
     bm = jnp.asarray(RNG.normal(0, 1, (b, s, n)), jnp.float32)
     cm = jnp.asarray(RNG.normal(0, 1, (b, s, n)), jnp.float32)
     y_xla, _ = ssd_chunked(x, dt, a, bm, cm, chunk=32)
-    y_pl = ops.ssd_scan(x, dt, a, bm, cm, chunk=32)
+    y_pl = ops.ssd_scan(x, dt, a, bm, cm, chunk=32, interpret=True)
     np.testing.assert_allclose(np.asarray(y_xla), np.asarray(y_pl),
                                atol=2e-4, rtol=1e-3)
 
@@ -201,7 +235,8 @@ def test_decode_attention_sweep(dtype, tol, w, d, hq, hkv, window):
     ap = slots + w * ((p - slots) // w)
     ap = np.where(ap <= p, ap, -1)  # future/unwritten slots empty
     ap = jnp.asarray(ap, jnp.int32)
-    o = ops.decode_attention(q, k, v, ap, pos, window=window)
+    o = ops.decode_attention(q, k, v, ap, pos, window=window,
+                             interpret=True)
     r = ref.decode_attention_ref(q, k, v, ap, pos, window=window)
     err = float(jnp.max(jnp.abs(o.astype(jnp.float32) - r.astype(jnp.float32))))
     assert err < tol, err
@@ -239,7 +274,7 @@ def test_decode_attention_matches_model_path():
     ap = lc["abs_pos"].at[bidx, slot].set(lc["pos"])
     out = ops.decode_attention(
         q[:, 0], jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), ap, lc["pos"],
-        window=cfg.window)
+        window=cfg.window, interpret=True)
     from repro.models.layers import dense
     y_kernel = dense(out.reshape(B, 1, -1), pl0["attn"]["wo"])
     np.testing.assert_allclose(np.asarray(y_model), np.asarray(y_kernel),

@@ -4,7 +4,8 @@ Covers the full tentpole contract: oracle parity on a MIXED store across all
 four backends (host / device / device+delta / sharded), the k > live-records
 exhaustion path, duplicate query points, deterministic ascending
 ``(distance, id)`` tie-breaking on co-located records, CDF-seed-underestimate
-ladder escalation, ``knn_topk`` impl equivalence (pallas == sort), and the
+ladder escalation, ``knn_topk`` impl equivalence (the Pallas kernel in
+interpret mode == sort, through the engine and on raw tie-heavy tiles), and the
 no-host-gather assertion (device and sharded ranking never pull candidate
 geometry to the host). The randomized sweep is marked ``property`` (tier-2:
 ``pytest -q -m property``) and skips gracefully without hypothesis.
@@ -194,15 +195,51 @@ def test_knn_topk_pallas_matches_sort():
     try:
         idx.config = dataclasses.replace(base, knn_topk="sort")
         a = idx.query(QueryBatch.knn(pts, k=6, backend="device"))
-        idx.config = dataclasses.replace(base, knn_topk="pallas")
+        idx.config = dataclasses.replace(base, knn_topk="interpret")
         b = idx.query(QueryBatch.knn(pts, k=6, backend="device"))
     finally:
         idx.config = base
     assert "topk=sort" in a.stages[-1].note
-    assert "topk=pallas" in b.stages[-1].note
+    assert "topk=interpret" in b.stages[-1].note
     for i in range(len(pts)):
         np.testing.assert_array_equal(a.ids[i], b.ids[i])
         np.testing.assert_array_equal(a.distances[i], b.distances[i])
+
+
+@pytest.mark.parametrize("q,b,k,dup", [
+    (3, 40, 6, 4),       # heavy distance ties, k < live lanes
+    (9, 130, 10, 1),     # odd Q and B: internal padding on both axes
+    (5, 7, 12, 2),       # k > live candidates: (inf, INT32_MAX) padding
+    (8, 64, 64, 3),      # k == B: full sort of the tile
+])
+def test_knn_topk_kernel_matches_two_key_sort(q, b, k, dup):
+    """The partial-selection kernel (interpret mode) against the two-key
+    ``lax.sort`` reference on raw tiles: repeated distances resolved by id,
+    repeated (distance, id) pairs kept as often as the sort keeps them, and
+    dead +inf lanes padding past the live candidates."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(q * 1000 + b)
+    d = rng.integers(0, 5, (q, b)).astype(np.float32) / dup  # many ties
+    ids = rng.integers(0, 3 * b, (q, b)).astype(np.int32)
+    ids[:, 1] = ids[:, 0]                       # a repeated id per row
+    d[:, 1] = d[:, 0]                           # ...at the same distance
+    dead = rng.random((q, b)) < 0.3
+    d[dead] = np.inf
+    ids[dead] = 2**31 - 1
+    want_d, want_i = ops.knn_topk(jnp.asarray(d), jnp.asarray(ids), k=k,
+                                  use_pallas=False)
+    got_d, got_i = ops.knn_topk(jnp.asarray(d), jnp.asarray(ids), k=k,
+                                interpret=True)
+    if k > b:                 # the reference cannot slice past the tile
+        want_d = np.concatenate(
+            [want_d, np.full((q, k - b), np.inf, np.float32)], axis=1)
+        want_i = np.concatenate(
+            [want_i, np.full((q, k - b), 2**31 - 1, np.int32)], axis=1)
+    np.testing.assert_array_equal(np.asarray(got_d), np.asarray(want_d))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
 
 
 @pytest.mark.parametrize("backend", ["device", "sharded"])
