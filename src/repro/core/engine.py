@@ -73,6 +73,7 @@ from .device import (DeltaTable, GLINSnapshot, HostCapture, _pow2ceil,
                      pods_from_store, snapshot_capture, snapshot_from_capture)
 from .index import GLIN, GLINConfig, QueryStats
 from .relations import get_relation
+from repro.utils import trace
 
 __all__ = ["EngineConfig", "QueryBatch", "QueryPlan", "QueryResult",
            "SpatialIndex"]
@@ -344,6 +345,9 @@ class SpatialIndex:
         # per-(backend, stage) telemetry aggregates (stats()["stages"]):
         # calls, wall_ms, queries, survivors, ladder escalations, delta sizes
         self._stage_totals: Dict[str, Dict[str, Dict[str, float]]] = {}
+        # query prologues (the ``glin.engine.plan`` span): count and ms
+        self._plans = 0
+        self._plan_ms = 0.0
 
     # ------------------------------------------------------------------ build
     @classmethod
@@ -370,23 +374,35 @@ class SpatialIndex:
             st["replicas"] = max(1, self.config.replicas)
             st["stages"] = {b: {s: dict(v) for s, v in per.items()}
                             for b, per in self._stage_totals.items()}
+            st["plans"] = self._plans
+            st["plan_ms"] = self._plan_ms
+            # process-wide: compiles on threads that ran no stage
+            st["compiles_other"] = trace.OUTSIDE["compiles"]
             return st
 
     def _record_stages(self, backend: str,
-                       stage_stats: List["qexec.StageStats"]) -> None:
+                       stage_stats: List["qexec.StageStats"],
+                       plan_ms: float = 0.0) -> None:
         """Fold one execution's per-stage telemetry into the aggregates
-        surfaced by ``stats()["stages"]`` (keyed backend -> stage label)."""
+        surfaced by ``stats()["stages"]`` (keyed backend -> stage label),
+        and its prologue's ``plan_ms`` into ``stats()["plan_ms"]``."""
         with self._lock:
+            self._plans += 1
+            self._plan_ms += plan_ms
             per = self._stage_totals.setdefault(backend, {})
             for ss in stage_stats:
                 ent = per.setdefault(ss.stage, {
                     "impl": ss.impl, "calls": 0, "skipped": 0,
-                    "wall_ms": 0.0, "queries": 0, "survivors": 0,
+                    "wall_ms": 0.0, "wait_ms": 0.0, "compiles": 0,
+                    "compile_ms": 0.0, "queries": 0, "survivors": 0,
                     "escalations": 0, "dispatches": 0, "delta_added": 0,
                     "delta_tombstoned": 0, "rungs": 0, "seed_hits": 0,
                     "merge_bytes": 0, "rung_hist": [], "notes": []})
                 ent["calls"] += 1
                 ent["wall_ms"] += ss.wall_ms
+                ent["wait_ms"] += ss.wait_ms
+                ent["compiles"] += ss.compiles
+                ent["compile_ms"] += ss.compile_ms
                 # the executing impl may differ per call (staged vs fused
                 # refine share the "refine" label): report the latest
                 ent["impl"] = ss.impl
@@ -616,10 +632,11 @@ class SpatialIndex:
                 # epoch — swap it in instead of rebuilding synchronously
                 self._poll_republish()
             if self.snapshot_is_stale():
-                cap = snapshot_capture(self.glin)
-                self._install_snapshot(
-                    self._pad_snapshot(snapshot_from_capture(cap)), cap,
-                    self._epoch, added=set(), tombstones=set())
+                with trace.span("glin.index.publish"):
+                    cap = snapshot_capture(self.glin)
+                    self._install_snapshot(
+                        self._pad_snapshot(snapshot_from_capture(cap)), cap,
+                        self._epoch, added=set(), tombstones=set())
             return self._snapshot
 
     def _install_snapshot(self, snap: GLINSnapshot, capture: HostCapture,
@@ -1165,17 +1182,20 @@ class SpatialIndex:
             if kw:
                 raise ValueError(f"{sorted(kw)} must be set on the QueryBatch "
                                  "itself")
-        with self._lock:
-            self._maintain_async()
-            plan = self.plan(batch)
-        rel = base = None
-        if batch.kind == "window":
-            rel = get_relation(batch.relation)
-            base = get_relation(rel.base_name())
-        ctx = qexec.ExecContext(index=self, batch=batch, plan=plan,
-                                rel=rel, base=base, replica=replica or 0)
-        qexec.compile_plan(plan).execute(ctx)
-        self._record_stages(plan.backend, ctx.stage_stats)
+        with trace.timed("glin.engine.plan") as t:
+            with self._lock:
+                self._maintain_async()
+                plan = self.plan(batch)
+            t.set_args(backend=plan.backend)
+            rel = base = None
+            if batch.kind == "window":
+                rel = get_relation(batch.relation)
+                base = get_relation(rel.base_name())
+            ctx = qexec.ExecContext(index=self, batch=batch, plan=plan,
+                                    rel=rel, base=base, replica=replica or 0)
+            eplan = qexec.compile_plan(plan)
+        eplan.execute(ctx)
+        self._record_stages(plan.backend, ctx.stage_stats, t.ms)
         return QueryResult(ids=ctx.ids, plan=plan, epoch=ctx.epoch,
                            stats=ctx.host_stats, distances=ctx.distances,
                            stages=ctx.stage_stats)

@@ -57,11 +57,15 @@ sharded steps are single programs — plus one per disambiguating bounds
 probe and one per kNN rank), and names in ``StageStats.note`` each
 implementation its attempts ran (``compaction=pallas``, ``dense``, ...), so
 a fallback in any attempt shows in ``stats()["stages"]`` and ``explain()``.
+Each stage runs inside the span ``glin.stage.<name>`` (``utils/trace.py``),
+whose host-clock pair is ``StageStats.wall_ms``; the single-chip refine
+stages open ``glin.refine.*`` spans per freeze, ladder attempt, device wait,
+escalation and assembly, sum their waits into ``StageStats.wait_ms``, and
+the compiles a stage triggers land in ``StageStats.compiles``.
 """
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, List, Optional, Tuple
 
 import jax
@@ -73,6 +77,7 @@ from .device import (batch_check_added, batch_knn_rank,
 from .index import QueryStats, initial_knn_radius
 from .index import knn as _host_knn
 from .relations import get_relation
+from repro.utils import trace
 
 __all__ = ["StageStats", "ExecContext", "Stage", "ExecutionPlan",
            "OverflowLadder", "compile_plan", "PIPELINE_STAGES"]
@@ -102,13 +107,19 @@ class StageStats:
     a refine stage ended on (budget 0 = single-stage dense, -1 = n/a);
     ``dispatches`` counts jitted programs launched (one per ladder attempt,
     +1 per disambiguating bounds probe or kNN rank — 0 for host/shared
-    stages that launch no device work)."""
+    stages that launch no device work). ``wait_ms`` is the part of
+    ``wall_ms`` the host spent blocked on device results (the
+    ``glin.refine.wait`` spans); ``compiles``/``compile_ms`` count the
+    backend compiles the stage triggered on its thread."""
 
     stage: str                       # primary canonical stage name
     impl: str                        # "host" | "device" | "fused" |
                                      # "sharded" | "shared"
     covers: Tuple[str, ...] = ()     # canonical stages this impl fuses
     wall_ms: float = 0.0
+    wait_ms: float = 0.0
+    compiles: int = 0
+    compile_ms: float = 0.0
     queries: int = 0
     survivors: int = -1
     escalations: int = 0
@@ -307,6 +318,21 @@ def _attempt_note(compaction: str, budget: int) -> str:
     return f"compaction={compaction}" if budget else "dense"
 
 
+def _wait(x, st: StageStats) -> np.ndarray:
+    """``np.asarray(x)`` under the ``glin.refine.wait`` span: the host
+    blocked on a device result, summed into ``st.wait_ms``."""
+    with trace.timed("glin.refine.wait") as t:
+        out = np.asarray(x)
+    st.wait_ms += t.ms
+    return out
+
+
+def _assemble(ctx: ExecContext, hits: np.ndarray) -> None:
+    """Device hit rows (-1 padded) -> ascending per-row id lists."""
+    with trace.span("glin.refine.assemble"):
+        ctx.ids = [np.sort(row[row >= 0]).astype(np.int64) for row in hits]
+
+
 # ------------------------------------------------------------------- stages
 class Stage:
     """One pipeline stage: fill ``ctx`` (and its own ``StageStats``). A
@@ -368,7 +394,7 @@ class DeviceRefineStage(Stage):
         idx, batch = ctx.index, ctx.batch
         cfg = idx.config
         patch = ctx.plan.backend == "device+delta"
-        with idx._lock:
+        with trace.span("glin.refine.freeze"), idx._lock:
             # freeze everything the unlocked compute below reads: the served
             # snapshot + payload (immutable device arrays), copies of the
             # delta sets and the live set — a writer landing after this
@@ -401,24 +427,27 @@ class DeviceRefineStage(Stage):
         base = ctx.base.name
         while True:
             ub = ladder.use_budget
-            hits, counts = eng.batch_query(
-                snap, wj, pods, mb, relation=base,
-                cap=ladder.cap, exact_budget=ub, compaction=comp)
-            st.dispatches += 1
-            st.add_note(_attempt_note(comp, ub))
-            counts = np.asarray(counts)
-            if (counts >= 0).all():
-                with idx._lock:
-                    # max-merge: a concurrent query may have grown it further
-                    idx._cap = max(idx._cap, ladder.cap)
-                break
-            if ladder.on_device_overflow(
-                    counts, ub,
-                    lambda: eng.batch_query_bounds(snap, wj, relation=base),
-                    q):
-                st.dispatches += 1            # disambiguating bounds probe
-        hits = np.asarray(hits)[:q]
-        ctx.ids = [np.sort(row[row >= 0]).astype(np.int64) for row in hits]
+            with trace.span("glin.refine.attempt", budget=ub,
+                            cap=ladder.cap):
+                hits, counts = eng.batch_query(
+                    snap, wj, pods, mb, relation=base,
+                    cap=ladder.cap, exact_budget=ub, compaction=comp)
+                st.dispatches += 1
+                st.add_note(_attempt_note(comp, ub))
+                counts = _wait(counts, st)
+                if (counts >= 0).all():
+                    with idx._lock:
+                        # max-merge: a concurrent query may have grown it
+                        idx._cap = max(idx._cap, ladder.cap)
+                    break
+                with trace.span("glin.refine.escalate"):
+                    if ladder.on_device_overflow(
+                            counts, ub,
+                            lambda: eng.batch_query_bounds(snap, wj,
+                                                           relation=base),
+                            q):
+                        st.dispatches += 1    # disambiguating bounds probe
+        _assemble(ctx, _wait(hits, st)[:q])
         st.survivors = _total(ctx.ids)
         st.escalations = ladder.escalations
         st.cap, st.budget = ladder.cap, ladder.use_budget
@@ -443,7 +472,7 @@ class FusedDeviceStage(Stage):
         idx, batch = ctx.index, ctx.batch
         cfg = idx.config
         patch = ctx.plan.backend == "device+delta"
-        with idx._lock:
+        with trace.span("glin.refine.freeze"), idx._lock:
             snap = idx._published_snapshot() if patch else idx.snapshot()
             payload = idx._device_payload(idx._snapshot_recs)
             snap, payload = idx._replica_view(ctx.replica, snap, payload)
@@ -464,33 +493,37 @@ class FusedDeviceStage(Stage):
         while True:
             ub = ladder.use_budget
             st.dispatches += 1
-            if idx._fusion_mode(base, ub) is None:
-                # dense escalation (survivors past max_cap): the staged
-                # dense path serves this attempt
-                hits, counts = eng.batch_query(
-                    snap, wj, pods, mb, relation=base,
-                    cap=ladder.cap, exact_budget=0, compaction="scan")
-                st.add_note("dense")
-                counts = np.asarray(counts)
+            dense = idx._fusion_mode(base, ub) is None
+            with trace.span("glin.refine.attempt", budget=0 if dense else ub,
+                            cap=ladder.cap):
+                if dense:
+                    # dense escalation (survivors past max_cap): the staged
+                    # dense path serves this attempt
+                    hits, counts = eng.batch_query(
+                        snap, wj, pods, mb, relation=base,
+                        cap=ladder.cap, exact_budget=0, compaction="scan")
+                    st.add_note("dense")
+                    counts = _wait(counts, st)
+                    if (counts >= 0).all():
+                        with idx._lock:
+                            idx._cap = max(idx._cap, ladder.cap)
+                        break
+                    with trace.span("glin.refine.escalate"):
+                        if ladder.on_device_overflow(
+                                counts, 0,
+                                lambda: eng.batch_query_bounds(
+                                    snap, wj, relation=base), q):
+                            st.dispatches += 1   # disambiguating bounds probe
+                    continue
+                hits, counts = eng.batch_query_fused(
+                    snap, wj, pods, relation=base, exact_budget=ub)
+                st.add_note("fusion=reference")
+                counts = _wait(counts, st)
                 if (counts >= 0).all():
-                    with idx._lock:
-                        idx._cap = max(idx._cap, ladder.cap)
                     break
-                if ladder.on_device_overflow(
-                        counts, 0,
-                        lambda: eng.batch_query_bounds(snap, wj,
-                                                       relation=base), q):
-                    st.dispatches += 1        # disambiguating bounds probe
-                continue
-            hits, counts = eng.batch_query_fused(
-                snap, wj, pods, relation=base, exact_budget=ub)
-            st.add_note("fusion=reference")
-            counts = np.asarray(counts)
-            if (counts >= 0).all():
-                break
-            ladder.on_capless_overflow(counts, ub)
-        hits = np.asarray(hits)[:q]
-        ctx.ids = [np.sort(row[row >= 0]).astype(np.int64) for row in hits]
+                with trace.span("glin.refine.escalate"):
+                    ladder.on_capless_overflow(counts, ub)
+        _assemble(ctx, _wait(hits, st)[:q])
         st.survivors = _total(ctx.ids)
         st.escalations = ladder.escalations
         st.cap, st.budget = ladder.cap, ladder.use_budget
@@ -764,7 +797,7 @@ class KnnDeviceStage(Stage):
         q, k = len(batch), int(batch.k)
         wins = np.concatenate([pts, pts], axis=1)    # degenerate windows
         patch = ctx.plan.backend == "device+delta"
-        with idx._lock:
+        with trace.span("glin.refine.freeze"), idx._lock:
             # same freeze contract as DeviceRefineStage: snapshot + payload
             # + delta copies captured under the lock, device compute outside
             # it — every rung serves the SAME frozen epoch
@@ -838,21 +871,28 @@ class KnnDeviceStage(Stage):
             # batch onto a wide budget.
             c1 = ladder.cap
             ub = b0 if b0 > 0 and (ladder.capless or b0 < c1) else 0
-            hits, ch = eng.batch_query(
-                snap, jnp.asarray(sq), pods, mb, relation="intersects",
-                cap=c1, exact_budget=ub, compaction=comp)
-            st.dispatches += 1
-            st.add_note(_attempt_note(comp, ub))
-            ch = np.asarray(ch)[: len(todo)]
-            good = ch >= 0
-            idk, dk, within = batch_knn_rank(
-                jnp.asarray(ctr), pods, hits, jnp.asarray(rr), k, impl,
-                tombstones=tomb, delta=dtab)
-            st.dispatches += 1
-            idk = np.array(idk[: len(todo)])     # writable: fat rows splice
-            dk = np.array(dk[: len(todo)])
-            within = np.array(within[: len(todo)])
+            with trace.span("glin.refine.attempt", budget=ub, cap=c1):
+                hits, ch = eng.batch_query(
+                    snap, jnp.asarray(sq), pods, mb, relation="intersects",
+                    cap=c1, exact_budget=ub, compaction=comp)
+                st.dispatches += 1
+                st.add_note(_attempt_note(comp, ub))
+                ch = _wait(ch, st)[: len(todo)]
+                good = ch >= 0
+                idk, dk, within = batch_knn_rank(
+                    jnp.asarray(ctr), pods, hits, jnp.asarray(rr), k, impl,
+                    tombstones=tomb, delta=dtab)
+                st.dispatches += 1
+                with trace.timed("glin.refine.wait") as t:
+                    # writable: fat rows splice
+                    idk = np.array(idk[: len(todo)])
+                    dk = np.array(dk[: len(todo)])
+                    within = np.array(within[: len(todo)])
+                st.wait_ms += t.ms
             fat = np.nonzero(~good)[0]
+            # a rung's overflowed rows re-run under the escalate span after
+            # its attempt closed: the rung's other rows are kept, so only
+            # the re-run's own overflowing attempts are thrown away
             if fat.size:
                 # tier 2: only the overflowed rows walk the escalating
                 # ladder (cap/budget grow to fit THEM, nobody else pays).
@@ -866,39 +906,41 @@ class KnnDeviceStage(Stage):
                 fi = (np.concatenate([fat, np.repeat(fat[-1:],
                                                      fb - fat.size)])
                       if fb > fat.size else fat)
-                try:
-                    fhits = _knn_refine(eng, idx, snap, pods, mb,
-                                        jnp.asarray(sq[fi]), comp, ladder,
-                                        st)
-                except OverflowError:
-                    # a straggler's radius outgrew max_cap: the host loop
-                    # has no cap — finish the stragglers there instead of
-                    # failing the whole batch
-                    st.add_note("straggler radius outgrew max_cap: "
-                                "host fallback")
-                    with idx._lock:
-                        for i in todo[fat]:
-                            hi, hd = _host_knn(idx.glin, pts[int(i)], k)
-                            out_ids[int(i)] = np.asarray(hi, np.int64)
-                            out_d[int(i)] = np.asarray(hd)
-                    done[todo[fat]] = True
-                else:
-                    fidk, fdk, fwit = batch_knn_rank(
-                        jnp.asarray(ctr[fi]), pods, fhits,
-                        jnp.asarray(rr[fi]), k, impl,
-                        tombstones=tomb, delta=dtab)
-                    st.dispatches += 1
-                    idk[fat] = np.asarray(fidk)[: fat.size]
-                    dk[fat] = np.asarray(fdk)[: fat.size]
-                    within[fat] = np.asarray(fwit)[: fat.size]
-                    good[fat] = True
-            settle = good & ((within >= k) | (within >= n_live))
-            for j in np.nonzero(settle)[0]:
-                i = int(todo[j])
-                keep = idk[j] >= 0
-                out_ids[i] = idk[j][keep].astype(np.int64)
-                out_d[i] = dk[j][keep].astype(np.float64)
-            done[todo[settle]] = True
+                with trace.span("glin.refine.escalate"):
+                    try:
+                        fhits = _knn_refine(eng, idx, snap, pods, mb,
+                                            jnp.asarray(sq[fi]), comp,
+                                            ladder, st)
+                    except OverflowError:
+                        # a straggler's radius outgrew max_cap: the host
+                        # loop has no cap — finish the stragglers there
+                        # instead of failing the whole batch
+                        st.add_note("straggler radius outgrew max_cap: "
+                                    "host fallback")
+                        with idx._lock:
+                            for i in todo[fat]:
+                                hi, hd = _host_knn(idx.glin, pts[int(i)], k)
+                                out_ids[int(i)] = np.asarray(hi, np.int64)
+                                out_d[int(i)] = np.asarray(hd)
+                        done[todo[fat]] = True
+                    else:
+                        fidk, fdk, fwit = batch_knn_rank(
+                            jnp.asarray(ctr[fi]), pods, fhits,
+                            jnp.asarray(rr[fi]), k, impl,
+                            tombstones=tomb, delta=dtab)
+                        st.dispatches += 1
+                        idk[fat] = _wait(fidk, st)[: fat.size]
+                        dk[fat] = _wait(fdk, st)[: fat.size]
+                        within[fat] = _wait(fwit, st)[: fat.size]
+                        good[fat] = True
+            with trace.span("glin.refine.assemble"):
+                settle = good & ((within >= k) | (within >= n_live))
+                for j in np.nonzero(settle)[0]:
+                    i = int(todo[j])
+                    keep = idk[j] >= 0
+                    out_ids[i] = idk[j][keep].astype(np.int64)
+                    out_d[i] = dk[j][keep].astype(np.float64)
+                done[todo[settle]] = True
             und = np.nonzero(~settle)[0]
             if und.size:
                 # count-informed radius growth: an undone row already has
@@ -939,22 +981,24 @@ def _knn_refine(eng, idx, snap, pods, mb, wj, comp, ladder, st):
     ``batch_knn_rank``; only the overflow-signal counts cross to the host."""
     while True:
         ub = ladder.use_budget
-        hits, counts = eng.batch_query(
-            snap, wj, pods, mb, relation="intersects",
-            cap=ladder.cap, exact_budget=ub, compaction=comp)
-        st.dispatches += 1
-        st.add_note(_attempt_note(comp, ub))
-        ch = np.asarray(counts)
-        if (ch >= 0).all():
-            with idx._lock:
-                idx._cap = max(idx._cap, ladder.cap)
-            return hits
-        if ladder.on_device_overflow(
-                ch, ub,
-                lambda: eng.batch_query_bounds(snap, wj,
-                                               relation="intersects"),
-                wj.shape[0]):
-            st.dispatches += 1                # disambiguating bounds probe
+        with trace.span("glin.refine.attempt", budget=ub, cap=ladder.cap):
+            hits, counts = eng.batch_query(
+                snap, wj, pods, mb, relation="intersects",
+                cap=ladder.cap, exact_budget=ub, compaction=comp)
+            st.dispatches += 1
+            st.add_note(_attempt_note(comp, ub))
+            ch = _wait(counts, st)
+            if (ch >= 0).all():
+                with idx._lock:
+                    idx._cap = max(idx._cap, ladder.cap)
+                return hits
+            with trace.span("glin.refine.escalate"):
+                if ladder.on_device_overflow(
+                        ch, ub,
+                        lambda: eng.batch_query_bounds(
+                            snap, wj, relation="intersects"),
+                        wj.shape[0]):
+                    st.dispatches += 1        # disambiguating bounds probe
 
 
 class KnnShardedStage(Stage):
@@ -1106,9 +1150,10 @@ class ExecutionPlan:
         for stage in self.stages:
             st = StageStats(stage=stage.name, impl=stage.impl,
                             covers=stage.covers, queries=len(ctx.batch))
-            t0 = time.perf_counter()
-            stage.run(ctx, st)
-            st.wall_ms = 1e3 * (time.perf_counter() - t0)
+            with trace.timed(f"glin.stage.{stage.name}",
+                             impl=stage.impl) as t, trace.in_stage(st):
+                stage.run(ctx, st)
+            st.wall_ms = t.ms
             ctx.stage_stats.append(st)
         return ctx
 

@@ -226,6 +226,7 @@ def refine_mask_pallas(windows: jax.Array, bounds: jax.Array, mbrs: jax.Array,
         out_specs=pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp, np_), jnp.int8),
         cost_estimate=_cost_estimate("mask", qp, np_),
+        name="refine_mask",
         interpret=interpret,
     )(windows, bounds, mbrs)
     return out[:q, :n]
@@ -250,6 +251,7 @@ def refine_count_pallas(windows: jax.Array, bounds: jax.Array, mbrs: jax.Array,
         out_specs=pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((qp, 1), jnp.int32),
         cost_estimate=_cost_estimate("count", qp, np_),
+        name="refine_count",
         interpret=interpret,
     )(windows, bounds, mbrs)
     return out[:q, 0]
@@ -299,6 +301,7 @@ def refine_compact_pallas(windows: jax.Array, bounds: jax.Array,
             jax.ShapeDtypeStruct((qp, 1), jnp.int32),
         ),
         cost_estimate=_cost_estimate("compact", qp, np_, bud),
+        name="refine_compact",
         interpret=interpret,
     )(windows, bounds, leaf_mbrs, rec_mbrs)
     return slots[:q, :budget], counts[:q, 0]
@@ -377,6 +380,7 @@ def knn_topk_pallas(d: jax.Array, ids: jax.Array, k: int,
             jax.ShapeDtypeStruct((qp, kp), jnp.int32),
         ),
         cost_estimate=_cost_estimate("knn", qp, bp, k),
+        name="knn_topk",
         interpret=interpret,
     )(dp, ip)
     return outd[:q, :k], outi[:q, :k]

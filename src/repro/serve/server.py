@@ -30,6 +30,7 @@ The old ``SlotServer`` (continuous-batching LM demo) lives in
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import os
 import threading
 import time
@@ -41,6 +42,7 @@ import numpy as np
 
 from repro.core.engine import QueryBatch, SpatialIndex
 from repro.core.relations import get_relation
+from repro.utils import trace
 
 __all__ = ["Rejected", "ServerConfig", "SpatialQueryServer"]
 
@@ -92,8 +94,9 @@ class ServerConfig:
         return max(1, min(max(self.replicas, 2), os.cpu_count() or 1))
 
 
-# one queued request: (ticket, tenant, relation, window)
-_Pending = Tuple[int, str, str, np.ndarray]
+# one queued request: (ticket, tenant, relation, window, perf_counter at
+# enqueue)
+_Pending = Tuple[int, str, str, np.ndarray, float]
 
 
 class SpatialQueryServer:
@@ -202,6 +205,10 @@ class SpatialQueryServer:
         self.cache_hits = 0
         self.cache_misses = 0
         self.coalesced = 0      # duplicate windows folded within a group
+        # queue wait, enqueue to the start of serving, summed over requests
+        self.queue_wait_ms = 0.0
+        self.queue_waited = 0
+        self._batch_seq = itertools.count()   # glin.serve.batch numbers
 
     # ------------------------------------------------------------------ cache
     def _record_plan(self, res) -> None:
@@ -321,7 +328,7 @@ class SpatialQueryServer:
             if q is None:
                 q = self._queues[tenant] = deque()
                 self._tenant_order.append(tenant)
-            q.append((ticket, tenant, relation, w))
+            q.append((ticket, tenant, relation, w, time.perf_counter()))
             self._depth += 1
             self._cond.notify_all()
         return ticket
@@ -391,7 +398,7 @@ class SpatialQueryServer:
         uniq: Dict[bytes, int] = {}
         slot: List[int] = []
         rows: List[np.ndarray] = []
-        for _, _, _, w in items:
+        for _, _, _, w, _ in items:
             k = w.tobytes()
             mi = uniq.get(k)
             if mi is None:
@@ -434,6 +441,10 @@ class SpatialQueryServer:
     def _hist_bucket(n: int) -> int:
         return 1 << max(n - 1, 0).bit_length()
 
+    @staticmethod
+    def _queue_wait_ms(items: List[_Pending], now: float) -> float:
+        return 1e3 * sum(now - item[4] for item in items)
+
     def flush(self) -> Dict[int, Any]:
         """Serve everything queued; returns ``{ticket: hit ids | Rejected}``.
 
@@ -448,12 +459,14 @@ class SpatialQueryServer:
             items = self._drain_locked(None)
             if not items and not self._rejected:
                 return {}
+            # charged at the commit: a failed flush requeues its requests
+            waited_ms = self._queue_wait_ms(items, time.perf_counter())
             gen = self.index.serving_generation
             out: Dict[int, Any] = {}
             cached: List[_Pending] = []
             by_rel: Dict[str, List[_Pending]] = {}
             for item in items:
-                ticket, tenant, rel, w = item
+                ticket, tenant, rel, w, _ = item
                 hit = self._cache_lookup(gen, w, rel)
                 if hit is not None:
                     out[ticket] = hit
@@ -486,7 +499,7 @@ class SpatialQueryServer:
         # ---- commit: every group succeeded ----
         with self._cond:
             for rel, g, (res, per_item, ncoal, rep, _dt) in results:
-                for (ticket, tenant, r, w), ids in zip(g, per_item):
+                for (ticket, tenant, r, w, _), ids in zip(g, per_item):
                     out[ticket] = ids
                     self._cache_store(gen, w, r, ids)
                     self._tenant(tenant)["served"] += 1
@@ -507,6 +520,8 @@ class SpatialQueryServer:
                     self.backend_counts.get("cache", 0) + len(cached))
             self.served_queries += len(out) - len(shed)
             self.served_batches += len(groups)
+            self.queue_wait_ms += waited_ms
+            self.queue_waited += len(items)
         return out
 
     def query(self, windows: np.ndarray, relation: str = "intersects",
@@ -617,7 +632,7 @@ class SpatialQueryServer:
             if (cfg.adaptive_batch and ahead
                     and depth < min(cfg.min_batch, cfg.max_batch)):
                 deadline = time.perf_counter() + self._gather_window()
-                with self._cond:
+                with trace.span("glin.serve.gather"), self._cond:
                     while (self._running and self._depth < cfg.min_batch):
                         rem = deadline - time.perf_counter()
                         if rem <= 0:
@@ -640,71 +655,83 @@ class SpatialQueryServer:
         pool = self._ensure_pool()
         futs = []
         for rel, g in by_rel.items():
-            self._slots.acquire()
-            futs.append(pool.submit(self._serve_group_task, rel, g))
+            with trace.span("glin.serve.slot_wait", q=len(g)):
+                self._slots.acquire()
+            futs.append(pool.submit(self._serve_group_task, rel, g,
+                                    next(self._batch_seq)))
         if wait:
             for f in futs:
                 f.result()
 
-    def _serve_group_task(self, rel: str, items: List[_Pending]) -> None:
+    def _serve_group_task(self, rel: str, items: List[_Pending],
+                          seq: int) -> None:
         """Pump-mode worker: serve one relation group, resolve its tickets.
         A failed group resolves every ticket as :class:`Rejected` (counted
-        in ``failed_batches``) — waiters never hang on an exception."""
-        try:
-            with self._cond:
-                gen = self.index.serving_generation
-                todo: List[_Pending] = []
-                for item in items:
-                    ticket, tenant, r, w = item
-                    hit = self._cache_lookup(gen, w, r)
-                    if hit is not None:
-                        self._done[ticket] = (hit, time.perf_counter())
+        in ``failed_batches``) — waiters never hang on an exception. The
+        group's queue wait is charged as it starts, since the pump may hold
+        drained requests while it waits for a worker slot."""
+        with trace.span("glin.serve.batch", batch=seq, q=len(items),
+                        rel=rel):
+            try:
+                with self._cond:
+                    self.queue_wait_ms += self._queue_wait_ms(
+                        items, time.perf_counter())
+                    self.queue_waited += len(items)
+                    gen = self.index.serving_generation
+                    todo: List[_Pending] = []
+                    for item in items:
+                        ticket, tenant, r, w, _ = item
+                        hit = self._cache_lookup(gen, w, r)
+                        if hit is not None:
+                            self._done[ticket] = (hit, time.perf_counter())
+                            self._tenant(tenant)["served"] += 1
+                            self.cache_hits += 1
+                            self.served_queries += 1
+                            self.backend_counts["cache"] = (
+                                self.backend_counts.get("cache", 0) + 1)
+                        else:
+                            todo.append(item)
+                    self._cond.notify_all()
+                if not todo:
+                    return
+                res, per_item, ncoal, rep, _dt = self._run_group(rel, todo)
+                now = time.perf_counter()
+                with trace.span("glin.serve.resolve", q=len(todo)), self._cond:
+                    for (ticket, tenant, r, w, _), ids in zip(todo, per_item):
+                        self._cache_store(gen, w, r, ids)
+                        self._done[ticket] = (ids, now)
                         self._tenant(tenant)["served"] += 1
-                        self.cache_hits += 1
-                        self.served_queries += 1
-                        self.backend_counts["cache"] = (
-                            self.backend_counts.get("cache", 0) + 1)
-                    else:
-                        todo.append(item)
-                self._cond.notify_all()
-            if not todo:
-                return
-            res, per_item, ncoal, rep, _dt = self._run_group(rel, todo)
-            now = time.perf_counter()
-            with self._cond:
-                for (ticket, tenant, r, w), ids in zip(todo, per_item):
-                    self._cache_store(gen, w, r, ids)
-                    self._done[ticket] = (ids, now)
-                    self._tenant(tenant)["served"] += 1
-                self._record_plan(res)
-                self.coalesced += ncoal
-                self.cache_misses += len(todo)
-                self.served_queries += len(todo)
-                self.served_batches += 1
-                self.replica_queries[rep] += len(todo)
-                b = self._hist_bucket(len(todo))
-                self.batch_hist[b] = self.batch_hist.get(b, 0) + 1
-                self._cond.notify_all()
-        except BaseException as e:   # noqa: BLE001 — resolved as Rejected
-            now = time.perf_counter()
-            with self._cond:
-                self.failed_batches += 1
-                for ticket, tenant, r, w in items:
-                    if ticket not in self._done:
-                        self._done[ticket] = (
-                            Rejected(f"serve error: {e!r}", tenant, r), now)
-                self._cond.notify_all()
-        finally:
-            self._slots.release()
+                    self._record_plan(res)
+                    self.coalesced += ncoal
+                    self.cache_misses += len(todo)
+                    self.served_queries += len(todo)
+                    self.served_batches += 1
+                    self.replica_queries[rep] += len(todo)
+                    b = self._hist_bucket(len(todo))
+                    self.batch_hist[b] = self.batch_hist.get(b, 0) + 1
+                    self._cond.notify_all()
+            except BaseException as e:  # noqa: BLE001 — resolved as Rejected
+                now = time.perf_counter()
+                with self._cond:
+                    self.failed_batches += 1
+                    for ticket, tenant, r, w, _ in items:
+                        if ticket not in self._done:
+                            self._done[ticket] = (Rejected(
+                                f"serve error: {e!r}", tenant, r), now)
+                    self._cond.notify_all()
+            finally:
+                self._slots.release()
 
     # ------------------------------------------------------------- telemetry
     def stats(self) -> dict:
         """One JSON-serializable snapshot of the serving tier. Includes the
-        facade's per-stage execution telemetry (``engine_stages``) so one
-        stats call covers the whole pipeline: queue → stage → replica."""
+        facade's per-stage execution telemetry (``engine_stages``) and plan
+        counters so one stats call covers the whole pipeline: queue (depth
+        and wait) → plan → stage → replica."""
         # grab engine telemetry before taking the server lock (the facade
         # has its own lock; never hold both)
-        eng_stages = self.index.stats().get("stages", {})
+        eng = self.index.stats()
+        eng_stages = eng.get("stages", {})
         with self._lock:
             return {
                 "queue_depth": self._depth,
@@ -724,7 +751,11 @@ class SpatialQueryServer:
                 "cache_hits": self.cache_hits,
                 "cache_misses": self.cache_misses,
                 "coalesced": self.coalesced,
+                "queue_wait_ms": self.queue_wait_ms,
+                "queue_waited": self.queue_waited,
                 "engine_stages": eng_stages,
+                "plans": eng["plans"],
+                "plan_ms": eng["plan_ms"],
                 "served_queries": self.served_queries,
                 "served_batches": self.served_batches,
                 "write_ops": self.write_ops,

@@ -5,8 +5,9 @@ vector gathers inside a kernel, primitives Mosaic cannot lower, kernels past
 the scoped VMEM. These tests lower the Pallas kernels and the jitted query
 programs at deployment widths for one chip of a described ``v5e:2x2``
 topology (and the sharded step for all four) and assert that a compiled
-kernel (``tpu_custom_call``) is in the program. Nothing runs: a compile that
-passes is not a chip run.
+kernel (``tpu_custom_call``) is in the program, and that the compact kernel
+keeps the op name the benchmark's trace reduction reads it by. Nothing
+runs: a compile that passes is not a chip run.
 """
 import dataclasses
 import os
@@ -16,6 +17,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from bench import trace as bench_trace
+from bench.work.compact import TRACE_NAME
 from repro.core.device import VertexPods, batch_query
 from repro.core.distributed import (build_glin_knn_step,
                                     build_glin_query_step, glin_input_specs)
@@ -53,6 +56,15 @@ def _compiled_text(fn, *args, **kw):
     return jax.jit(fn, **kw).lower(*args).compile().as_text()
 
 
+def _kernel_calls(text, kernel):
+    """Custom calls of a compiled program that the benchmark's trace
+    reduction counts as ``kernel`` (device op events carry the
+    instruction's text)."""
+    calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+             if " custom-call(" in ln]
+    return bench_trace.kernel_ns([(c, 0.0, 1.0) for c in calls], kernel)
+
+
 @pytest.mark.parametrize("budget", [256, COMPACT_CHUNK, 4 * COMPACT_CHUNK])
 def test_compact_kernel_compiles(one_chip, budget):
     """One output chunk, and a budget filled in four chunks."""
@@ -62,6 +74,7 @@ def test_compact_kernel_compiles(one_chip, budget):
         _spec((Q, 4), f32, one_chip), _spec((Q, 2), i32, one_chip),
         _spec((N_CHIP, 4), f32, one_chip), _spec((N_CHIP, 4), f32, one_chip))
     assert "tpu_custom_call" in text
+    assert _kernel_calls(text, TRACE_NAME) == 1
 
 
 def test_count_kernel_compiles(one_chip):
@@ -113,6 +126,7 @@ def test_query_program_compiles(one_chip, budget):
                                        compaction="pallas"),
         snap, wins, pods, mbrs)
     assert "tpu_custom_call" in text
+    assert _kernel_calls(text, TRACE_NAME) == 1
 
 
 @pytest.mark.parametrize("kind", ["query", "knn"])
