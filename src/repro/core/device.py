@@ -643,7 +643,8 @@ def _compact_kernel_refine(rel, windows: jax.Array, s: GLINSnapshot,
                            end: jax.Array, kb: int, interpret: bool
                            ) -> Tuple[jax.Array, jax.Array]:
     """Probe runs -> the Pallas ``refine_compact`` kernel (capless: it
-    streams every slot tile) -> the shared exact stage. Overflow encodes the
+    streams the whole slot table in wide record tiles, ``compact_tile``)
+    -> the shared exact stage. Overflow encodes the
     TOTAL survivor count (``-(survivors) - 1``), so the caller can size its
     budget ladder in one step."""
     from repro.kernels import ops

@@ -66,7 +66,8 @@ def refine_compact(windows: jax.Array, bounds: jax.Array,
     """Fused mask + compaction: (Q,4) probe windows, (Q,2) i32 slot runs,
     slot-aligned (N,4) leaf/record MBR tables -> (slots (Q, budget) i32
     [-1 padded], counts (Q,) i32 total survivors; ``counts > budget``
-    signals truncation)."""
+    signals truncation). The kernel's record tile follows N
+    (``refine.compact_tile``)."""
     if not use_pallas:
         return ref.refine_compact_ref(windows, bounds, leaf_mbrs, rec_mbrs,
                                       budget, prefilter)

@@ -44,18 +44,20 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BQ = 8
 DEFAULT_BN = 512
-COMPACT_BN = 256      # smaller record tiles: the one-hot scatter block is
-                      # (budget, BN) per query row in VMEM
+COMPACT_BN = 8192     # widest record tile of the compact kernel: a grid
+                      # step costs a fixed overhead, so the mask runs over
+                      # wide tiles (``compact_tile``)
+COMPACT_SUB = 256     # scatter sub-tile: the one-hot block is (chunk, SUB)
+                      # per query row in VMEM
 COMPACT_CHUNK = 1024  # output columns one pass of the compact kernel
-                      # fills: its (chunk, BN) int32 one-hot block (1 MiB)
+                      # fills: its (chunk, SUB) int32 one-hot block (1 MiB)
                       # must fit the scoped VMEM next to the streamed tiles.
                       # Larger budgets take one pass per chunk
 _NEVER = 2e30         # padding MBR coordinate: intersects nothing
 
 
-def _mbr_rows(ref):
-    """(4, BN) lane-dense MBR tile -> its four (1, BN) coordinate rows."""
-    m = ref[...]
+def _mbr_rows(m):
+    """(4, W) lane-dense MBR tile -> its four (1, W) coordinate rows."""
     return m[0:1, :], m[1:2, :], m[2:3, :], m[3:4, :]
 
 
@@ -66,23 +68,22 @@ def _win_cols(ref):
 
 
 def _intersects(w, m):
-    """(BQ,1) window columns x (1,BN) MBR rows -> (BQ,BN) bool overlap."""
+    """(BQ,1) window columns x (1,W) MBR rows -> (BQ,W) bool overlap."""
     return ((w[0] <= m[2]) & (m[0] <= w[2])
             & (w[1] <= m[3]) & (m[1] <= w[3]))
 
 
-def _in_run(bounds_ref, nb, bn, shape):
-    """Slot-run test: tile column c is slot ``nb*bn + c``; the run is the
-    row's ``[start, end)`` from the (BQ, 2) bounds block."""
-    b = bounds_ref[...]
-    slot = nb * bn + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+def _in_run(b, first, shape):
+    """Slot-run test: tile column c is slot ``first + c``; the run is the
+    row's ``[start, end)`` from the (BQ, 2) bounds block ``b``."""
+    slot = first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     return (slot >= b[:, 0:1]) & (slot < b[:, 1:2])
 
 
 def _tile_mask(win_ref, mbr_ref, bounds_ref, nb, bn):
     """(BQ,4) windows x (4,BN) record MBRs -> (BQ,BN) bool."""
-    inter = _intersects(_win_cols(win_ref), _mbr_rows(mbr_ref))
-    return inter & _in_run(bounds_ref, nb, bn, inter.shape)
+    inter = _intersects(_win_cols(win_ref), _mbr_rows(mbr_ref[...]))
+    return inter & _in_run(bounds_ref[...], nb * bn, inter.shape)
 
 
 def _mask_kernel(win_ref, bounds_ref, mbr_ref, out_ref, *, bn):
@@ -102,32 +103,33 @@ def _count_kernel(win_ref, bounds_ref, mbr_ref, out_ref, *, bn):
     out_ref[...] += partial_counts
 
 
-def _compact_tile_mask(win_ref, lmbr_ref, rmbr_ref, bounds_ref, nb, bn,
-                       prefilter):
-    """Fused interval + leaf-MBR + record-MBR tests -> (BQ, BN) bool.
+def _compact_tile_mask(w, lm, rm, b, first, prefilter):
+    """Fused interval + leaf-MBR + record-MBR tests -> (BQ, W) bool.
 
-    ``win_ref`` holds the PROBE window (already padded for dwithin-style
-    relations); ``prefilter`` selects the record-MBR test shape:
-    "intersects" (record MBR meets the probe window) or "contains" (record
-    MBR covers the window — the ``within`` prefilter)."""
-    w = _win_cols(win_ref)
-    rm = _mbr_rows(rmbr_ref)
-    leaf_ok = _intersects(w, _mbr_rows(lmbr_ref))
+    ``w`` holds the four columns of the PROBE windows (already padded for
+    dwithin-style relations), ``lm`` / ``rm`` the (4, W) leaf and record
+    MBR tiles whose column 0 is slot ``first``, ``b`` the (BQ, 2) slot
+    runs. ``prefilter`` selects the record-MBR test shape: "intersects"
+    (record MBR meets the probe window) or "contains" (record MBR covers
+    the window — the ``within`` prefilter)."""
+    rm = _mbr_rows(rm)
+    leaf_ok = _intersects(w, _mbr_rows(lm))
     if prefilter == "contains":
         rec_ok = ((rm[0] <= w[0]) & (rm[1] <= w[1])
                   & (w[2] <= rm[2]) & (w[3] <= rm[3]))
     else:
         rec_ok = _intersects(w, rm)
-    return leaf_ok & rec_ok & _in_run(bounds_ref, nb, bn, leaf_ok.shape)
+    return leaf_ok & rec_ok & _in_run(b, first, leaf_ok.shape)
 
 
-def _exclusive_prefix(m32, bn):
-    """Exclusive prefix sum along the lanes of a (BQ, BN) 0/1 tile, as one
+def _exclusive_prefix(m32):
+    """Exclusive prefix sum along the lanes of a (BQ, W) 0/1 tile, as one
     MXU product with the upper-triangular ones matrix (Mosaic has no
     cumsum). Exact: the 0/1 operands are exact in bf16, and the partial
-    sums (<= BN) are exact in the f32 accumulator."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1)
+    sums (<= W) are exact in the f32 accumulator."""
+    w = m32.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (w, w), 1)
     tri = (row <= col).astype(jnp.bfloat16)
     incl = jnp.dot(m32.astype(jnp.bfloat16), tri,
                    preferred_element_type=jnp.float32)
@@ -141,16 +143,25 @@ def _compact_kernel(win_ref, bounds_ref, lmbr_ref, rmbr_ref,
     ``[c * chunk, (c + 1) * chunk)`` — into the revisited (BQ, chunk)
     output block.
 
+    A grid step costs a fixed overhead whatever its width, so the record
+    tile is wide (``compact_tile``) and the mask and the per-row survivor
+    count run over the whole tile. The scatter works on ``COMPACT_SUB``-slot
+    sub-tiles: on a tile that puts a survivor into this chunk, a rolled loop
+    masks each sub-tile again from the MBR blocks and scatters those that
+    put a survivor into this chunk, carrying the running per-row count
+    across them (a Python loop would unroll BN / SUB scatters and multiply
+    the compile time).
+
     ``count_ref`` ((BQ, 1): a rank-1 (BQ,) block is neither the whole array
     nor a multiple of 128 lanes, which Mosaic refuses) carries the running
     per-query survivor count across the record axis (restarted for every
     chunk, so the last chunk leaves the total); a survivor's output column
-    is that running count plus its exclusive within-tile prefix sum. The
-    scatter itself is a one-hot reduction (TPU vector units have no
-    scatter), one query row at a time over a (chunk, BN) block so every
-    operand stays 2D, and runs only on tiles that put a survivor into this
-    chunk. Survivors past the budget only advance the count — overflow is
-    ``count > budget``, signalled to the caller, never silent truncation."""
+    is that running count plus its exclusive within-sub-tile prefix sum.
+    The scatter itself is a one-hot reduction (TPU vector units have no
+    scatter), one query row at a time over a (chunk, SUB) block so every
+    operand stays 2D. Survivors past the budget only advance the count —
+    overflow is ``count > budget``, signalled to the caller, never silent
+    truncation."""
     c = pl.program_id(1)
     nb = pl.program_id(2)
 
@@ -159,29 +170,61 @@ def _compact_kernel(win_ref, bounds_ref, lmbr_ref, rmbr_ref,
         slots_ref[...] = jnp.full_like(slots_ref, -1)
         count_ref[...] = jnp.zeros_like(count_ref)
 
-    mask = _compact_tile_mask(win_ref, lmbr_ref, rmbr_ref, bounds_ref, nb, bn,
-                              prefilter)
-    m32 = mask.astype(jnp.int32)
+    w = _win_cols(win_ref)
+    b = bounds_ref[...]
+    first = nb * bn                                  # slot of column 0
     base = count_ref[...]                            # (BQ, 1)
-    tile = m32.sum(axis=1, keepdims=True)
+    tile = _compact_tile_mask(w, lmbr_ref[...], rmbr_ref[...], b, first,
+                              prefilter).astype(jnp.int32).sum(
+                                  axis=1, keepdims=True)
     count_ref[...] = base + tile
     lo = c * chunk
-    lands = (tile > 0) & (base < lo + chunk) & (base + tile > lo)
 
-    @pl.when(jnp.max(lands.astype(jnp.int32)) > 0)
+    def lands(at, n):
+        """Does some row's run of ``n`` survivors, placed from output
+        column ``at`` on, put one into this chunk?"""
+        hit = (n > 0) & (at < lo + chunk) & (at + n > lo)
+        return jnp.max(hit.astype(jnp.int32)) > 0
+
+    @pl.when(lands(base, tile))
     def _scatter():
-        pos = base + _exclusive_prefix(m32, bn) - lo  # column in this chunk
-        sel = mask & (pos >= 0) & (pos < chunk)
-        lane1 = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1) + 1
-        out_col = jax.lax.broadcasted_iota(jnp.int32, (chunk, bn), 0)
-        # written[:, r] = 1 + the tile lane that lands in each output
-        # column of query row r (0 where none does)
-        written = jnp.concatenate(
-            [jnp.where((out_col == pos[r:r + 1]) & sel[r:r + 1], lane1, 0)
-             .sum(axis=1, keepdims=True) for r in range(mask.shape[0])],
-            axis=1).T                                # (BQ, chunk)
-        slots_ref[...] = jnp.where(written > 0, nb * bn + written - 1,
-                                   slots_ref[...])
+        def sub_tile(s, at):
+            off = pl.multiple_of(s * COMPACT_SUB, COMPACT_SUB)
+            cols = pl.ds(off, COMPACT_SUB)
+            mask = _compact_tile_mask(w, lmbr_ref[:, cols], rmbr_ref[:, cols],
+                                      b, first + off, prefilter)
+            m32 = mask.astype(jnp.int32)
+            n = m32.sum(axis=1, keepdims=True)
+
+            @pl.when(lands(at, n))
+            def _place():
+                pos = at + _exclusive_prefix(m32) - lo  # column in chunk
+                sel = mask & (pos >= 0) & (pos < chunk)
+                lane1 = jax.lax.broadcasted_iota(
+                    jnp.int32, (1, COMPACT_SUB), 1) + 1
+                out_col = jax.lax.broadcasted_iota(
+                    jnp.int32, (chunk, COMPACT_SUB), 0)
+                # written[:, r] = 1 + the sub-tile lane that lands in each
+                # output column of query row r (0 where none does)
+                written = jnp.concatenate(
+                    [jnp.where((out_col == pos[r:r + 1]) & sel[r:r + 1],
+                               lane1, 0).sum(axis=1, keepdims=True)
+                     for r in range(mask.shape[0])],
+                    axis=1).T                        # (BQ, chunk)
+                slots_ref[...] = jnp.where(written > 0,
+                                           first + off + written - 1,
+                                           slots_ref[...])
+
+            return at + n
+
+        jax.lax.fori_loop(0, bn // COMPACT_SUB, sub_tile, base)
+
+
+def compact_tile(n: int) -> int:
+    """Record tile of the compact kernel over an ``n``-slot table: the
+    widest tile, or the whole table rounded up to scatter sub-tiles when it
+    is narrower (a small store is not padded to a wide tile)."""
+    return min(COMPACT_BN, -(-max(n, 1) // COMPACT_SUB) * COMPACT_SUB)
 
 
 def _grids(q, n, bq, bn):
@@ -260,7 +303,7 @@ def refine_count_pallas(windows: jax.Array, bounds: jax.Array, mbrs: jax.Array,
 def refine_compact_pallas(windows: jax.Array, bounds: jax.Array,
                           leaf_mbrs: jax.Array, rec_mbrs: jax.Array,
                           budget: int, prefilter: str = "intersects",
-                          bq: int = DEFAULT_BQ, bn: int = COMPACT_BN,
+                          bq: int = DEFAULT_BQ, bn: int | None = None,
                           interpret: bool = False):
     """Fused mask + in-VMEM compaction.
 
@@ -269,14 +312,21 @@ def refine_compact_pallas(windows: jax.Array, bounds: jax.Array,
     (slots (Q, budget) int32 [-1 padded, ascending slot order],
      counts (Q,) int32 TOTAL mask survivors — ``counts > budget`` means the
      compacted list is truncated and the caller must re-issue).
+
+    ``bn`` is the record tile, a multiple of ``COMPACT_SUB``; None picks
+    ``compact_tile(N)``.
     """
     if prefilter not in ("intersects", "contains"):
         raise ValueError(f"unsupported prefilter {prefilter!r}")
     if budget < 1:
         raise ValueError(f"budget must be positive, got {budget}")
     q = windows.shape[0]
+    bn = compact_tile(leaf_mbrs.shape[0]) if bn is None else bn
+    if bn < 1 or bn % COMPACT_SUB:
+        raise ValueError(f"record tile must be a positive multiple of "
+                         f"{COMPACT_SUB}, got {bn}")
     # lane-aligned output columns, filled one chunk per pass over the
-    # record tiles: any budget fits, VMEM holds one (chunk, bn) block
+    # record tiles: any budget fits, VMEM holds one (chunk, SUB) block
     bud = max(128, -(-budget // 128) * 128)
     chunk = min(bud, COMPACT_CHUNK)
     bud = -(-bud // chunk) * chunk
@@ -300,7 +350,7 @@ def refine_compact_pallas(windows: jax.Array, bounds: jax.Array,
             jax.ShapeDtypeStruct((qp, bud), jnp.int32),
             jax.ShapeDtypeStruct((qp, 1), jnp.int32),
         ),
-        cost_estimate=_cost_estimate("compact", qp, np_, bud),
+        cost_estimate=_cost_estimate("compact", qp, np_, bud, bq=bq, bn=bn),
         name="refine_compact",
         interpret=interpret,
     )(windows, bounds, leaf_mbrs, rec_mbrs)
@@ -391,7 +441,7 @@ def knn_topk_pallas(d: jax.Array, ids: jax.Array, k: int,
 # ---------------------------------------------------------------------------
 def refine_cost(kind: str, q: int, n: int, budget: int = 0,
                 verts: int = 0, bq: int = DEFAULT_BQ,
-                bn: int = DEFAULT_BN) -> dict:
+                bn: int | None = None) -> dict:
     """Bytes/flops model of one kernel invocation.
 
     ``kind``: "mask" | "count" | "compact" | "exact" | "knn" —
@@ -405,7 +455,8 @@ def refine_cost(kind: str, q: int, n: int, budget: int = 0,
     the gather width of the batch's widest surviving pow2 width-bucket (the
     vertex-pool pods gather per-bucket, see ``core.device.VertexPods``),
     NOT the store-wide dense padding — callers should pass ``pow2ceil`` of
-    the surviving ring width they expect.
+    the surviving ring width they expect. ``bn`` is the compact kernel's
+    record tile (None: ``compact_tile(n)``); the other kernels ignore it.
     """
     tiles_q = -(-q // bq)
     if kind == "exact":
@@ -428,20 +479,32 @@ def refine_cost(kind: str, q: int, n: int, budget: int = 0,
         flops = q * n * verts * 40 + q * k * n * 3.0
         return {"flops": float(flops), "bytes_accessed": float(bytes_accessed),
                 "transcendentals": float(q * k)}     # sqrt on the k winners
-    # streaming kernels: each query row-tile streams the full MBR table(s),
-    # the compact kernel once per COMPACT_CHUNK output columns
-    streams = (2 * -(-max(budget, 1) // COMPACT_CHUNK) if kind == "compact"
-               else 1)
-    bytes_accessed = tiles_q * n * 16 * streams + q * 24
+    if kind == "compact":
+        # the grid: every (row tile, output chunk) pass streams both MBR
+        # tables in whole record tiles and masks every pair; a tile that
+        # places a survivor masks its sub-tiles again, and each sub-tile
+        # that places one runs the MXU prefix and the (chunk, SUB) one-hot
+        # scatter per row — each at most once per placed survivor
+        bn = compact_tile(n) if bn is None else bn
+        n = -(-n // bn) * bn
+        bud = max(budget, 1)
+        passes = -(-bud // COMPACT_CHUNK)
+        chunk = min(bud, COMPACT_CHUNK)
+        tiles = tiles_q * min(passes * n // bn, bq * bud)
+        subs = tiles_q * min(passes * n // COMPACT_SUB, bq * bud)
+        bytes_accessed = (tiles_q * passes * n * 32 + q * 24
+                          + q * (bud + 1) * 4)
+        flops = (10.0 * bq * (tiles_q * passes * n + tiles * bn)
+                 + subs * bq * COMPACT_SUB * 2.0 * (COMPACT_SUB + chunk))
+        return {"flops": float(flops), "bytes_accessed": float(bytes_accessed),
+                "transcendentals": 0}
+    # mask / count: each query row-tile streams the full MBR table
+    bytes_accessed = tiles_q * n * 16 + q * 24
     flops = q * n * 10.0          # interval + MBR comparisons per pair
     if kind == "mask":
         bytes_accessed += q * n   # int8 mask writeback
     elif kind == "count":
         bytes_accessed += tiles_q * bq * 4
-    elif kind == "compact":
-        flops += q * n * 6.0      # prefix sums
-        flops += q * n * float(max(budget, 1)) * 2.0   # one-hot scatter
-        bytes_accessed += q * (max(budget, 1) + 1) * 4
     else:
         raise ValueError(f"unknown kernel kind {kind!r}")
     return {"flops": float(flops), "bytes_accessed": float(bytes_accessed),
@@ -450,7 +513,7 @@ def refine_cost(kind: str, q: int, n: int, budget: int = 0,
 
 def sharded_refine_cost(q: int, n: int, budget: int, shards: int,
                         verts: int = 0, bq: int = DEFAULT_BQ,
-                        bn: int = DEFAULT_BN) -> dict:
+                        bn: int | None = None) -> dict:
     """Per-device cost of the SHARDED compact+refine pipeline
     (``core.distributed.build_glin_query_step`` with ``exact_budget``).
 
@@ -474,7 +537,7 @@ def sharded_refine_cost(q: int, n: int, budget: int, shards: int,
 
 def sharded_knn_cost(q: int, n: int, budget: int, k: int, shards: int,
                      verts: int = 0, bq: int = DEFAULT_BQ,
-                     bn: int = DEFAULT_BN) -> dict:
+                     bn: int | None = None) -> dict:
     """Per-device cost of the SHARDED device-complete kNN rung
     (``core.distributed.build_glin_knn_step``): the local compact+refine
     over the shard's N/shards slice, the local exact-distance top-k over its
@@ -497,8 +560,8 @@ def sharded_knn_cost(q: int, n: int, budget: int, k: int, shards: int,
     }
 
 
-def _cost_estimate(kind: str, q: int, n: int, budget: int = 0):
-    c = refine_cost(kind, q, n, budget)
+def _cost_estimate(kind: str, q: int, n: int, budget: int = 0, **tiles):
+    c = refine_cost(kind, q, n, budget, **tiles)
     return pl.CostEstimate(flops=int(c["flops"]),
                            bytes_accessed=int(c["bytes_accessed"]),
                            transcendentals=0)

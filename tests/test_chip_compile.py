@@ -29,6 +29,8 @@ from repro.utils.compat import make_auto_mesh
 Q = 64                  # windows per batch
 N_CHIP = 1 << 22        # slots of a 4M-record single-chip store
 N_MESH = 1 << 24        # records of the 16M-record four-chip store
+N_CELL = 10_002_432     # slots of the benchmark's 10M-record store (padded
+                        # to the snapshot's 4,096-slot quantum)
 MAX_WIDTH = 64          # widest ring of the mixed store
 
 
@@ -73,6 +75,19 @@ def test_compact_kernel_compiles(one_chip, budget):
         lambda w, b, lm, rm: refine_compact_pallas(w, b, lm, rm, budget),
         _spec((Q, 4), f32, one_chip), _spec((Q, 2), i32, one_chip),
         _spec((N_CHIP, 4), f32, one_chip), _spec((N_CHIP, 4), f32, one_chip))
+    assert "tpu_custom_call" in text
+    assert _kernel_calls(text, TRACE_NAME) == 1
+
+
+@pytest.mark.parametrize("budget", [256, COMPACT_CHUNK])
+def test_compact_kernel_compiles_at_cell_width(one_chip, budget):
+    """The benchmark cell's two ladder budgets at its padded slot count:
+    the wide record tile and its scatter fit the scoped VMEM."""
+    f32, i32 = jnp.float32, jnp.int32
+    text = _compiled_text(
+        lambda w, b, lm, rm: refine_compact_pallas(w, b, lm, rm, budget),
+        _spec((Q, 4), f32, one_chip), _spec((Q, 2), i32, one_chip),
+        _spec((N_CELL, 4), f32, one_chip), _spec((N_CELL, 4), f32, one_chip))
     assert "tpu_custom_call" in text
     assert _kernel_calls(text, TRACE_NAME) == 1
 

@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.refine import COMPACT_CHUNK
+from repro.kernels.refine import (COMPACT_BN, COMPACT_CHUNK, COMPACT_SUB,
+                                  compact_tile, refine_compact_pallas)
 
 RNG = np.random.default_rng(0)
 
@@ -139,6 +140,78 @@ def test_refine_compact_multi_chunk_budgets(budget):
     assert c[0] == n and c[1] > COMPACT_CHUNK    # several chunks filled
     kept = (np.asarray(s) >= 0).sum(axis=1)
     np.testing.assert_array_equal(kept, np.minimum(c, budget))
+
+
+def _wide_tile_case(n):
+    """Even slots hold a record whose MBR covers the unit square (every
+    probe window meets it and lies inside it), odd slots small random
+    boxes, so a run's survivors fill every sub-tile it touches under both
+    prefilters. Rows probe a point-sized window (or a random one) over
+    runs placed on the sub-tile and record-tile edges of a 1,024-slot
+    tile; the table is not a whole number of tiles."""
+    rng = np.random.default_rng(23)
+    rmbrs = rng.uniform(0, 0.95, (n, 4)).astype(np.float32)
+    rmbrs[:, 2:] = rmbrs[:, :2] + rng.uniform(0.001, 0.05, (n, 2))
+    rmbrs[0::2] = (-1.0, -1.0, 2.0, 2.0)
+    lmbrs = rmbrs + np.array([-0.02, -0.02, 0.02, 0.02], np.float32)
+    runs = [(0, n),            # every tile; its survivors fill several chunks
+            (1100, 3900),      # crosses chunk 0 -> 1 at slot 3146, in tile 3
+            (255, 257),        # straddles a sub-tile edge
+            (1023, 1025),      # straddles a record-tile edge
+            (50, 50),          # empty run
+            (60, 40),          # inverted (empty) run
+            (n - 10, n),       # ends in the padded last tile
+            (300, n - 300),    # a random window over most of the table
+            (2047, 2561),      # a random window from a tile's last slot
+            (0, n),            # a window that meets nothing
+            (0, 1),            # one slot
+            (4095, 4097)]      # two slots across a record-tile edge
+    wins = np.tile(np.array([[0.5, 0.5, 0.5001, 0.5001]], np.float32),
+                   (len(runs), 1))
+    wins[7] = (0.2, 0.3, 0.7, 0.6)
+    wins[8] = (0.1, 0.1, 0.4, 0.9)
+    wins[9] = (3.0, 3.0, 4.0, 4.0)
+    return (jnp.asarray(wins), jnp.asarray(np.array(runs, np.int32)),
+            jnp.asarray(lmbrs), jnp.asarray(rmbrs))
+
+
+# record tiles of 1,024 slots (four scatter sub-tiles) over 5,000 slots:
+# runs and survivors straddle sub-tile, record-tile and chunk edges
+@pytest.mark.parametrize("budget", [256, 1024, COMPACT_CHUNK + 1,
+                                    4 * COMPACT_CHUNK])
+@pytest.mark.parametrize("prefilter", ["intersects", "contains"])
+def test_refine_compact_wide_record_tiles(budget, prefilter):
+    n, bn = 5000, 4 * COMPACT_SUB
+    args = _wide_tile_case(n)
+    s, c = refine_compact_pallas(*args, budget, prefilter, bn=bn,
+                                 interpret=True)
+    sr, cr = ref.refine_compact_ref(*args, budget, prefilter)
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(cr))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(sr))
+    c = np.asarray(c)
+    assert c[0] > 2 * COMPACT_CHUNK and c[1] > COMPACT_CHUNK
+    assert all(c[r] in (1, 2) for r in (2, 3, 11)) and c[10] == 1
+    assert c[4] == c[5] == c[9] == 0
+    kept = (np.asarray(s) >= 0).sum(axis=1)
+    np.testing.assert_array_equal(kept, np.minimum(c, budget))
+
+
+@pytest.mark.parametrize("n,tile", [(1, COMPACT_SUB), (37, COMPACT_SUB),
+                                    (1000, 4 * COMPACT_SUB),
+                                    (20_000, COMPACT_BN),
+                                    (10_002_432, COMPACT_BN)])
+def test_compact_tile_follows_the_table(n, tile):
+    """The record tile is the widest tile, or a narrow table rounded up
+    to whole scatter sub-tiles."""
+    assert compact_tile(n) == tile
+    assert tile % COMPACT_SUB == 0 and tile <= COMPACT_BN
+
+
+@pytest.mark.parametrize("bn", [0, 100, COMPACT_SUB + 128])
+def test_refine_compact_rejects_a_ragged_record_tile(bn):
+    args = _wide_tile_case(600)
+    with pytest.raises(ValueError, match="record tile"):
+        refine_compact_pallas(*args, 256, bn=bn, interpret=True)
 
 
 @pytest.mark.parametrize("q,n", [(3, 100), (13, 999), (30, 2047)])
